@@ -24,9 +24,10 @@ dynamic shapes. All inner products/denominators are f32.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from functools import partial
-from typing import Callable, NamedTuple
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -371,14 +372,20 @@ class SolveSharding(NamedTuple):
                 ``qn_apply_multi`` runs device-local over batch and the only
                 collective is the feature reduce on the coefficient block.
 
-    Both default to identity; hooks must be cheap (``with_sharding_constraint``
-    closures). The whole-batch convergence reduction (``jnp.all(conv)`` in
-    the loop condition) is the one unavoidable cross-device step-count
-    collective — it is what drives early exit for the batched solve.
+    ``kernels``  a context (no arguments) in which the solve traces its
+                Pallas kernels per device shard
+                (``kernels.ops.sharded_kernels`` of the caller's ShardCtx).
+
+    The layout hooks default to identity; they must be cheap
+    (``with_sharding_constraint`` closures). The whole-batch convergence
+    reduction (``jnp.all(conv)`` in the loop condition) is the one
+    unavoidable cross-device step-count collective — it is what drives early
+    exit for the batched solve.
     """
 
     state: Callable[[Array], Array]
     memory: Callable[[Array], Array]
+    kernels: Callable[[], Any] = contextlib.nullcontext
 
 
 # Module-level identity hooks: a stable default object keeps jit caches warm

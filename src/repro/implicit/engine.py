@@ -38,7 +38,11 @@ import numpy as np
 
 from repro.core.solvers import SolveCarry, reset_carry_rows
 from repro.implicit.config import ImplicitConfig
-from repro.implicit.fixed_point import ImplicitStats, prepare_flat_problem
+from repro.implicit.fixed_point import (
+    ImplicitStats,
+    kernel_scope,
+    prepare_flat_problem,
+)
 from repro.implicit.registry import SOLVERS
 from repro.obs import metrics as obs_metrics
 
@@ -111,9 +115,11 @@ def batched_solve(
     freeze = None if valid is None else ~valid
 
     solver = SOLVERS.get(cfg.forward.solver)
-    res = _builtin_solvers.call_solver(
-        solver, lambda z: f_flat(params, x, z), z0_flat, cfg.solver_cfg(),
-        sharding=sharding, freeze_mask=freeze, carry=carry)
+    with kernel_scope(sharding):
+        res = _builtin_solvers.call_solver(
+            solver, lambda z: f_flat(params, x, z), z0_flat,
+            cfg.solver_cfg(), sharding=sharding, freeze_mask=freeze,
+            carry=carry)
     z = res.z
     if valid is not None:
         # padding/finished slots return their input state bit-for-bit

@@ -19,6 +19,7 @@ backward evaluates one fresh VJP of f at z*.
 
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 from typing import Any, Callable, NamedTuple
 
@@ -27,6 +28,7 @@ import jax.numpy as jnp
 
 from repro.core.solvers import SolveCarry, SolveSharding, init_solve_carry
 from repro.implicit.config import ImplicitConfig
+from repro.kernels import ops as kernel_ops
 from repro.implicit.estimators import estimate_cotangent
 from repro.implicit.pytree import ravel_state
 from repro.implicit.registry import SOLVERS
@@ -70,7 +72,14 @@ def solve_sharding(ctx, state_axes) -> SolveSharding | None:
     return SolveSharding(
         state=lambda a: ctx.constrain(a, axes),
         memory=lambda a: ctx.constrain(a, ("qn_mem",) + axes),
+        kernels=lambda: kernel_ops.sharded_kernels(ctx),
     )
+
+
+def kernel_scope(sharding: SolveSharding | None):
+    """Context in which a solve traces its kernels per device shard of the
+    solve's mesh (a no-op off-mesh)."""
+    return contextlib.nullcontext() if sharding is None else sharding.kernels()
 
 
 def prepare_flat_problem(f, z0, ctx, state_axes):
@@ -96,9 +105,10 @@ def prepare_flat_problem(f, z0, ctx, state_axes):
 def _solve_forward(f_z, z0, cfg: ImplicitConfig, outer_grad=None,
                    sharding=None, freeze_mask=None, carry=None):
     solver = SOLVERS.get(cfg.forward.solver)
-    return _builtin_solvers.call_solver(
-        solver, f_z, z0, cfg.solver_cfg(), outer_grad=outer_grad,
-        sharding=sharding, freeze_mask=freeze_mask, carry=carry)
+    with kernel_scope(sharding):
+        return _builtin_solvers.call_solver(
+            solver, f_z, z0, cfg.solver_cfg(), outer_grad=outer_grad,
+            sharding=sharding, freeze_mask=freeze_mask, carry=carry)
 
 
 def _bind_outer(outer_grad, params, x):
@@ -163,6 +173,13 @@ def _implicit_fwd(f, cfg: ImplicitConfig, outer_grad, sharding, params, x, z0,
 
 def _implicit_bwd(f, cfg: ImplicitConfig, outer_grad, sharding, saved,
                   cotangents):
+    # traced after the forward's scopes have closed: re-open the kernel
+    # scope for the VJP of f and the estimator's solves
+    with kernel_scope(sharding):
+        return _implicit_bwd_body(f, cfg, sharding, saved, cotangents)
+
+
+def _implicit_bwd_body(f, cfg: ImplicitConfig, sharding, saved, cotangents):
     params, x, z_star, H, status, carry = saved  # carry: shape structs only
     w, _stats_bar, _carry_bar = cotangents  # stats/carry carry no gradient
 

@@ -1,17 +1,24 @@
-"""Pallas TPU flash attention (forward) with GQA and causal block skipping.
+"""Pallas TPU attention: flash-attention forward (prefill / training) and
+single-token decode, both with GQA and a per-row valid KV length.
 
-Grid: (batch, q_heads, num_q_blocks, num_kv_blocks), executed sequentially on
-TPU — the online-softmax running max/denominator/accumulator live in VMEM
-scratch and carry across the kv-block grid dimension. Causality is enforced
-at two granularities: whole kv-blocks strictly above the diagonal are skipped
-via ``pl.when`` (no FLOPs once the compiler hoists the branch), and the
-diagonal block applies an element mask.
+Prefill runs head-major: the wrapper moves heads in front of the sequence
+(``(B, S, H, hd) -> (B, H, S, hd)``) so a block is a ``(rows, hd)`` slab whose
+last two dims the TPU lowering accepts (``hd`` is the whole axis).  Grid
+``(batch, q_heads, q blocks, kv blocks)``, executed sequentially: the
+online-softmax running max/denominator/accumulator live in VMEM scratch and
+carry across the kv-block axis.  Kv blocks wholly above the causal diagonal
+are skipped with ``pl.when``; the diagonal block and padding are masked by
+position.  GQA is handled in the index_map (kv head ``h // group``).
 
-GQA is handled in the index_map: kv blocks for q-head ``h`` come from kv-head
-``h // group``, so no materialized head broadcast.
+Sequence lengths need not divide the blocks: q, k and v are zero-padded up to
+the block and padded keys are masked by ``kv_length`` (scalar-prefetched into
+SMEM), so any prompt length compiles.
 
-The block sizes (128, 128) align the MXU contraction dims; head_dim is
-expected to be a multiple of 8 (all assigned architectures satisfy this).
+Decode (one query per row) keeps the cache layout ``(B, T, KV, hd)``: a block
+is ``(blk_k, KV, hd)`` — its last two dims are whole axes — and all heads of a
+row are scored at once on the VPU (a decode step is bound by reading the
+cache, not by FLOPs).  Queries are regrouped as ``(group, KV, hd)`` so every
+product is an elementwise broadcast against the kv block.
 """
 
 from __future__ import annotations
@@ -26,14 +33,27 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
+_ROWS = 16  # sublane tile of a bf16 block
+
+
+def _round_up(n: int, mult: int) -> int:
+    return ((n + mult - 1) // mult) * mult
+
+
+def _pad_axis(x: jax.Array, axis: int, size: int) -> jax.Array:
+    if x.shape[axis] == size:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, size - x.shape[axis])
+    return jnp.pad(x, pad)
 
 
 def _flash_kernel(
-    q_ref,      # (1, blk_q, 1, hd)
-    k_ref,      # (1, blk_k, 1, hd)
-    v_ref,      # (1, blk_k, 1, hd)
-    len_ref,    # (1, 1) valid kv length for this batch row
-    out_ref,    # (1, blk_q, 1, hd)
+    len_ref,    # (B,) int32 valid kv length per row (SMEM, scalar prefetch)
+    q_ref,      # (blk_q, hd)
+    k_ref,      # (blk_k, hd)
+    v_ref,      # (blk_k, hd)
+    out_ref,    # (blk_q, hd)
     m_scr,      # (blk_q, 1) f32 running max
     l_scr,      # (blk_q, 1) f32 running denominator
     acc_scr,    # (blk_q, hd) f32 accumulator
@@ -42,8 +62,9 @@ def _flash_kernel(
     causal: bool,
     blk_q: int,
     blk_k: int,
-    q_offset_blocks: int,
+    q_offset: int,
 ):
+    b = pl.program_id(0)
     iq = pl.program_id(2)
     ik = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -54,38 +75,37 @@ def _flash_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # absolute block row (in kv coordinates) of this q block
-    q_blk_abs = iq + q_offset_blocks
-    run = (ik <= q_blk_abs) if causal else (ik >= 0)
+    # kv position of this q block's last row; blocks past it are all masked
+    q_last = iq * blk_q + blk_q - 1 + q_offset
+    run = (ik * blk_k <= q_last) if causal else (ik >= 0)
 
     @pl.when(run)
     def _body():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale   # (blk_q, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # (blk_k, hd)
-        s = q @ k.T                                          # (blk_q, blk_k)
-
+        s = jax.lax.dot_general(
+            q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale     # (blk_q, blk_k)
         kpos = ik * blk_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = kpos < len_ref[0, 0]
+        valid = kpos < len_ref[b]
         if causal:
-            qpos = (q_blk_abs * blk_q
+            qpos = (iq * blk_q + q_offset
                     + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
             valid = valid & (kpos <= qpos)
         s = jnp.where(valid, s, NEG_INF)
 
-        m_prev = m_scr[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_new = corr * l_scr[:, 0] + jnp.sum(p, axis=1)
-        vv = v_ref[0, :, 0, :].astype(jnp.float32)           # (blk_k, hd)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + p @ vv
-        m_scr[:, 0] = m_new
-        l_scr[:, 0] = l_new
+        l_scr[...] = corr * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        denom = jnp.maximum(l_scr[:, 0], 1e-30)
-        out_ref[0, :, 0, :] = (acc_scr[...] / denom[:, None]).astype(out_ref.dtype)
+        denom = jnp.maximum(l_scr[...], 1e-30)
+        out_ref[...] = (acc_scr[...] / denom).astype(out_ref.dtype)
 
 
 @functools.partial(
@@ -108,52 +128,77 @@ def flash_attention_pallas(
     t, kvh = k.shape[1], k.shape[2]
     group = h // kvh
     scale = (hd ** -0.5) if scale is None else scale
-    blk_q = min(block_q, s)
-    blk_k = min(block_k, t)
-    if s % blk_q or t % blk_k:
-        raise ValueError(f"seq {s}/{t} must divide block sizes {blk_q}/{blk_k}")
-    nq, nk = s // blk_q, t // blk_k
-    # When q is the tail of a longer kv axis (chunked prefill), q block 0 sits
-    # at kv block (t - s) / blk_q. For self-attention t == s -> offset 0.
-    q_offset_blocks = (t - s) // blk_q if causal else 0
+    blk_q = min(block_q, _round_up(s, _ROWS))
+    blk_k = min(block_k, _round_up(t, _ROWS))
+    s_p, t_p = _round_up(s, blk_q), _round_up(t, blk_k)
+    # q row i sits at kv position i + (t - s): the tail of a longer kv axis
+    # (chunked prefill); self-attention has t == s -> offset 0
+    q_offset = (t - s) if causal else 0
     if kv_length is None:
         kv_length = jnp.full((b,), t, jnp.int32)
-    len2d = kv_length.reshape(b, 1).astype(jnp.int32)
+
+    qh = _pad_axis(jnp.swapaxes(q, 1, 2), 2, s_p)          # (B, H, S_p, hd)
+    kh = _pad_axis(jnp.swapaxes(k, 1, 2), 2, t_p)          # (B, KV, T_p, hd)
+    vh = _pad_axis(jnp.swapaxes(v, 1, 2), 2, t_p)
 
     kernel = functools.partial(
-        _flash_kernel,
-        scale=scale,
-        causal=causal,
-        blk_q=blk_q,
-        blk_k=blk_k,
-        q_offset_blocks=q_offset_blocks,
-    )
-    return pl.pallas_call(
+        _flash_kernel, scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k,
+        q_offset=q_offset)
+    q_spec = pl.BlockSpec((None, None, blk_q, hd),
+                          lambda b_, h_, iq, ik, ln: (b_, h_, iq, 0))
+    kv_spec = pl.BlockSpec((None, None, blk_k, hd),
+                           lambda b_, h_, iq, ik, ln: (b_, h_ // group, ik, 0))
+    out = pl.pallas_call(
         kernel,
-        grid=(b, h, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, blk_q, 1, hd), lambda b_, h_, iq, ik: (b_, iq, h_, 0)),
-            pl.BlockSpec(
-                (1, blk_k, 1, hd),
-                lambda b_, h_, iq, ik, g=group: (b_, ik, h_ // g, 0),
-            ),
-            pl.BlockSpec(
-                (1, blk_k, 1, hd),
-                lambda b_, h_, iq, ik, g=group: (b_, ik, h_ // g, 0),
-            ),
-            pl.BlockSpec((1, 1), lambda b_, h_, iq, ik: (b_, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, blk_q, 1, hd), lambda b_, h_, iq, ik: (b_, iq, h_, 0)
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h, s_p // blk_q, t_p // blk_k),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((blk_q, 1), jnp.float32),
+                pltpu.VMEM((blk_q, 1), jnp.float32),
+                pltpu.VMEM((blk_q, hd), jnp.float32),
+            ],
         ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((blk_q, 1), jnp.float32),
-            pltpu.VMEM((blk_q, 1), jnp.float32),
-            pltpu.VMEM((blk_q, hd), jnp.float32),
-        ],
+        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
         interpret=interpret,
-    )(q, k, v, len2d)
+    )(kv_length.astype(jnp.int32), qh, kh, vh)
+    return jnp.swapaxes(out[:, :, :s], 1, 2)
+
+
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, out_ref, m_scr, l_scr,
+                   acc_scr, *, scale: float, group: int, blk_k: int):
+    b = pl.program_id(0)
+    ik = pl.program_id(1)
+
+    @pl.when(ik == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    k = k_ref[...].astype(jnp.float32)                      # (blk_k, KV, hd)
+    v = v_ref[...].astype(jnp.float32)
+    kpos = ik * blk_k + jax.lax.broadcasted_iota(
+        jnp.int32, (k.shape[0], k.shape[1], 1), 0)
+    valid = kpos < len_ref[b]                               # (blk_k, KV, 1)
+    for g in range(group):
+        qg = q_ref[g].astype(jnp.float32) * scale           # (KV, hd)
+        s = jnp.sum(k * qg[None], axis=-1, keepdims=True)   # (blk_k, KV, 1)
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_scr[g]                                   # (KV, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+        p = jnp.exp(s - m_new[None])
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[g] = corr * l_scr[g] + jnp.sum(p, axis=0)
+        acc_scr[g] = acc_scr[g] * corr + jnp.sum(p * v, axis=0)
+        m_scr[g] = m_new
+
+    @pl.when(ik == pl.num_programs(1) - 1)
+    def _finalize():
+        out_ref[...] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                        ).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "block_k", "interpret"))
@@ -164,21 +209,43 @@ def decode_attention_pallas(
     kv_length: jax.Array,  # (B,)
     *,
     scale: float | None = None,
-    block_k: int = 512,
+    block_k: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
-    """Single-token flash-decode: q_len=1 specialization (no q tiling; the
-    whole per-head query row lives in registers, kv streams in blocks)."""
+    """Single-token flash-decode: the cache streams in ``(blk_k, KV, hd)``
+    blocks, all query heads of a row are scored against each block.  A
+    ``(KV, hd)`` slab pads to whole (16, 128) tiles in VMEM, so 128 cache
+    rows per block keep the double-buffered blocks and their f32 copies
+    inside the default scoped VMEM (512 does not fit at 36 x 64 heads)."""
     b, h, hd = q.shape
-    out = flash_attention_pallas(
-        q[:, None],
-        k,
-        v,
-        kv_length,
-        causal=False,
-        scale=scale,
-        block_q=1,
-        block_k=min(block_k, k.shape[1]),
+    t, kvh = k.shape[1], k.shape[2]
+    group = h // kvh
+    scale = (hd ** -0.5) if scale is None else scale
+    blk_k = min(block_k, _round_up(t, _ROWS))
+    t_p = _round_up(t, blk_k)
+    kp, vp = _pad_axis(k, 1, t_p), _pad_axis(v, 1, t_p)
+    # head h = kv_head * group + g  ->  (B, group, KV, hd)
+    qg = jnp.swapaxes(q.reshape(b, kvh, group, hd), 1, 2)
+
+    q_spec = pl.BlockSpec((None, group, kvh, hd),
+                          lambda b_, ik, ln: (b_, 0, 0, 0))
+    kv_spec = pl.BlockSpec((None, blk_k, kvh, hd),
+                           lambda b_, ik, ln: (b_, ik, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, scale=scale, group=group,
+                          blk_k=blk_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, t_p // blk_k),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((group, kvh, 1), jnp.float32),
+                pltpu.VMEM((group, kvh, 1), jnp.float32),
+                pltpu.VMEM((group, kvh, hd), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
         interpret=interpret,
-    )
-    return out[:, 0]
+    )(kv_length.astype(jnp.int32), qg, kp, vp)
+    return jnp.swapaxes(out, 1, 2).reshape(b, h, hd)

@@ -11,18 +11,21 @@ backend, and ``force_impl`` overrides globally for tests):
   "flash_xla"        | tiled online-softmax attention in | auto on CPU for
                      | plain XLA (memory-faithful to the | attention with
                      | Pallas kernel)                    | S*T >= 2^20 cells
-  "pallas"           | compiled Pallas TPU kernels       | auto on TPU (the
+  "pallas"           | compiled Pallas TPU kernels       | always on TPU (the
                      |                                   | deployment path)
   "pallas_interpret" | Pallas kernel bodies interpreted  | explicit only:
                      | in Python on CPU                  | kernel test sweeps
                      |                                   | (./test.sh kernels)
 
+On a TPU every op runs its Pallas kernel: asking for a CPU path there raises
+instead of quietly running something else.
+
 Ops dispatched here: ``qn_apply`` (single-RHS SHINE inverse application),
 ``qn_apply_multi`` (K stacked RHS, per-RHS H vs H^T, ONE stream over U/V),
-``lowrank_append`` (fused Broyden ring-buffer update writing only the target
-slot row), ``broyden_step`` (the apply AND the append of one Broyden
-iteration in a single launch — the hot path of the forward solve),
-``attention``, ``decode_attention``, ``rmsnorm``.
+``lowrank_append`` (Broyden ring-buffer update in place), ``broyden_step``
+(the U/V work of one Broyden iteration in a single launch plus a slot-row
+write — the hot path of the forward solve), ``attention``,
+``decode_attention``, ``rmsnorm``.
 
 Precision: the qN ring may be stored bf16 (``SolverConfig.qn_dtype``); every
 path upcasts U/V tiles on read and accumulates coefficients, denominators
@@ -32,12 +35,13 @@ actual ``u.dtype.itemsize``, and a ``qn_ring_bytes`` gauge labelled by dtype
 records the resident ring footprint.
 
 SPMD posture (the sharded batched fixed-point engine): the solvers pin the
-(U, V) chain batch-sharded next to the state, so on the ref path every qn
-op is fully device-local over batch; when the *feature* axes are
+(U, V) chain batch-sharded next to the state.  On the ref path GSPMD keeps
+every qn op device-local over batch; when the *feature* axes are
 TP-sharded, the RHS are grouped by transpose flag and each group's
 coefficients reduce in ONE einsum over the whole (K_g, m, B) block —
-a single collective per flag group, not one per RHS (kernels/ref.py).
-The Pallas path always sees the per-shard local view.
+a single collective per flag group, not one per RHS (kernels/ref.py).  A
+pallas_call cannot be partitioned, so under a mesh every kernel path runs
+under ``shard_map`` on its device's rows (see :func:`sharded_kernels`).
 
 The qn ops also keep trace-time stream statistics
 (``reset_qn_stream_stats``/``qn_stream_stats``): inside a ``lax.while_loop``
@@ -55,12 +59,15 @@ their own.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 import functools
 from typing import Literal, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
 from repro.obs import metrics as obs_metrics
@@ -73,7 +80,6 @@ from repro.kernels.qn_apply import (
     broyden_step_pallas,
     lowrank_append_pallas,
     qn_apply_multi_pallas,
-    qn_apply_pallas,
 )
 from repro.kernels.rmsnorm import rmsnorm_pallas
 
@@ -94,11 +100,13 @@ def force_impl(impl: Impl | None) -> None:
 
 
 def _resolve(impl: Impl | None) -> Impl:
-    if _FORCED_IMPL is not None:
-        return _FORCED_IMPL
-    if impl not in (None, "auto"):
-        return impl
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+    want = _FORCED_IMPL if _FORCED_IMPL is not None else impl
+    if jax.default_backend() == "tpu":
+        if want not in (None, "auto", "pallas"):
+            raise ValueError(f"kernel impl {want!r} does not run on a TPU; "
+                             "only 'pallas' does")
+        return "pallas"
+    return "ref" if want in (None, "auto") else want
 
 
 # ---------------------------------------------------------------------------
@@ -179,21 +187,71 @@ def _pad_memory_axis(u2, v2, mask):
     return u2, v2, mask
 
 
+# A pallas_call cannot be partitioned by GSPMD.  Every kernel here is
+# independent across batch rows (and attention across heads), so under a
+# mesh each device runs it on its own rows: ``sharded_kernels(ctx)`` (opened
+# by the model entry points, the solve entry points and the DEQ backward
+# while they trace) makes the kernel paths below run under ``jax.shard_map``
+# with the ctx's layout of those axes.  Axes the kernel cannot split stay
+# whole on every device.
+_KERNEL_CTX: contextvars.ContextVar = contextvars.ContextVar(
+    "kernel_ctx", default=None)
+
+
+@contextlib.contextmanager
+def sharded_kernels(ctx):
+    """While tracing inside this context, kernels map over ``ctx.mesh``
+    (a ``ShardCtx``; one without a mesh, or None, is a no-op)."""
+    token = _KERNEL_CTX.set(
+        ctx if ctx is not None and ctx.mesh is not None else None)
+    try:
+        yield
+    finally:
+        _KERNEL_CTX.reset(token)
+
+
+def _names(ndim: int, at: dict[int, str | None]) -> tuple:
+    """Logical axis names of an ``ndim`` array: ``at={1: "batch"}`` puts
+    "batch" on dim 1, every other dim is unsplit."""
+    return tuple(at.get(i) for i in range(ndim))
+
+
+def _on_mesh(fn, in_names, out_names):
+    """``fn`` as is, or mapped over the active mesh.  ``in_names`` holds
+    each argument's logical axis names (None: replicated); ``out_names`` the
+    output's, or a list of each output's.  A logical axis splits only if
+    every argument dim carrying it divides by its mesh extent."""
+    ctx = _KERNEL_CTX.get()
+    if ctx is None:
+        return fn
+
+    def mapped(*args):
+        ok: dict[str, bool] = {}
+        for names, a in zip(in_names, args):
+            for n, dim in zip(names or (), jnp.shape(a)):
+                if n is not None:
+                    ok[n] = ok.get(n, True) and dim % ctx.axis_size(n) == 0
+
+        def spec(names):
+            if names is None:
+                return P()
+            return ctx.rules.spec([n if n and ok[n] else None for n in names])
+
+        out_specs = (tuple(spec(n) for n in out_names)
+                     if isinstance(out_names, list) else spec(out_names))
+        return jax.shard_map(
+            fn, mesh=ctx.mesh, in_specs=tuple(spec(n) for n in in_names),
+            out_specs=out_specs, check_vma=False)(*args)
+    return mapped
+
+
 def qn_apply(u, v, x, alpha, mask, impl: Impl | None = None) -> jax.Array:
-    impl = _resolve(impl)
-    _record_stream(u, (False,))
-    if impl == "ref":
+    """``H @ x`` for one right-hand side; the kernel path is the K = 1 case
+    of :func:`qn_apply_multi`."""
+    if _resolve(impl) == "ref":
+        _record_stream(u, (False,))
         return ref.qn_apply_ref(u, v, x, alpha, mask)
-    # Kernel path: flatten feature dims (per-shard local view on TPU).
-    m, bsz = u.shape[0], u.shape[1]
-    feat_shape = x.shape[1:]
-    u2, v2 = u.reshape(m, bsz, -1), v.reshape(m, bsz, -1)
-    x2 = x.reshape(bsz, -1)
-    u2, v2, mask = _pad_memory_axis(u2, v2, mask)
-    out = qn_apply_pallas(
-        u2, v2, x2, alpha, mask, interpret=(impl == "pallas_interpret")
-    )
-    return out.reshape((bsz,) + feat_shape)
+    return qn_apply_multi(u, v, x[None], alpha, mask, (False,), impl=impl)[0]
 
 
 def qn_apply_multi(u, v, xs, alpha, mask,
@@ -217,100 +275,68 @@ def qn_apply_multi(u, v, xs, alpha, mask,
     _record_stream(u, transpose)
     if impl == "ref":
         return ref.qn_apply_multi_ref(u, v, xs, alpha, mask, transpose)
-    m, bsz = u.shape[0], u.shape[1]
-    feat_shape = xs.shape[2:]
-    u2, v2 = u.reshape(m, bsz, -1), v.reshape(m, bsz, -1)
-    xs2 = xs.reshape(kk, bsz, -1)
-    u2, v2, mask = _pad_memory_axis(u2, v2, mask)
-    out = qn_apply_multi_pallas(
-        u2, v2, xs2, alpha, mask, transpose=transpose, block_d=block_d,
-        interpret=(impl == "pallas_interpret"),
-    )
-    return out.reshape((kk, bsz) + feat_shape)
+
+    def local(u, v, xs, alpha, mask):
+        m, bsz = u.shape[0], u.shape[1]
+        u2, v2 = u.reshape(m, bsz, -1), v.reshape(m, bsz, -1)
+        u2, v2, mask2 = _pad_memory_axis(u2, v2, mask)
+        out = qn_apply_multi_pallas(
+            u2, v2, xs.reshape(kk, bsz, -1), alpha, mask2,
+            transpose=transpose, block_d=block_d,
+            interpret=(impl == "pallas_interpret"))
+        return out.reshape(xs.shape)
+
+    ring = _names(u.ndim, {1: "batch"})
+    rhs = _names(xs.ndim, {1: "batch"})
+    return _on_mesh(local, (ring, ring, rhs, None, ring[:2]), rhs)(
+        u, v, xs, jnp.asarray(alpha, jnp.float32), mask)
 
 
-def qn_apply_multi_sharded(u, v, xs, alpha, mask,
-                           transpose: Sequence[bool] | None = None,
-                           *,
-                           mesh,
-                           batch_axes: str | tuple[str, ...] = "data",
-                           impl: Impl | None = None,
-                           block_d: int = 512) -> jax.Array:
-    """Explicit ``shard_map`` route for the batch-sharded fused application.
-
-    The GSPMD route (plain :func:`qn_apply_multi` under a sharding
-    constraint) already runs the kernel on the per-shard local view, but the
-    tile geometry it lowers with is whatever the partitioner picks.  This
-    wrapper maps the kernel over the DP mesh axes EXPLICITLY: every shard
-    executes one ``pallas_call`` whose ``block_d`` feature tile (and padded
-    local batch) is pinned at trace time — deterministic per-shard tiling
-    for the TPU path, per the ROADMAP's shard_map open item.
-
-    ``u, v: (m, B, *F)`` and ``xs: (K, B, *F)`` must be batch-shardable over
-    ``batch_axes`` (B divisible by the product of those mesh axis sizes);
-    feature axes stay local (the fused op is device-local over batch — no
-    collectives are issued in the mapped body).
-    """
-    from jax.sharding import PartitionSpec as P
-
-    from repro.parallel.sharding import shard_map_compat
-
-    axes = (batch_axes,) if isinstance(batch_axes, str) else tuple(batch_axes)
-    dp = 1
-    for a in axes:
-        dp *= int(mesh.shape[a])
-    bsz = u.shape[1]
-    if bsz % dp != 0:
-        raise ValueError(
-            f"batch {bsz} not divisible by mesh extent {dp} of {axes}")
-    feat_rest = (None,) * (u.ndim - 2)
-    uv_spec = P(None, axes, *feat_rest)
-    xs_spec = P(None, axes, *feat_rest)
-    mask_spec = P(None, axes)
-
-    def local(u_, v_, xs_, alpha_, mask_):
-        return qn_apply_multi(u_, v_, xs_, alpha_, mask_, transpose,
-                              impl=impl, block_d=block_d)
-
-    return shard_map_compat(
-        local, mesh,
-        in_specs=(uv_spec, uv_spec, xs_spec, P(), mask_spec),
-        out_specs=xs_spec,
-    )(u, v, xs, jnp.asarray(alpha, jnp.float32), mask)
+def _write_slot_rows(u, v, slot, row_u, row_v):
+    """Write ``row_*[b]`` into ring slot ``slot[b]`` of sample ``b``: a row
+    scatter into the (donated, loop-carried) ring — B rows written, not m."""
+    bidx = jnp.arange(u.shape[1])
+    return (u.at[slot, bidx].set(row_u, unique_indices=True),
+            v.at[slot, bidx].set(row_v, unique_indices=True))
 
 
 def lowrank_append(u, v, s, hy, b, inv_den, slot, upd,
                    impl: Impl | None = None):
     """Fused Broyden ring-buffer update: write ``a = (s - Hy) * inv_den``
-    and ``b`` into ring slot ``slot`` of U/V for samples where ``upd``,
-    without a gather/scatter round-trip (the Pallas path touches only the
-    target row).  Returns ``(new_u, new_v, evicted_u, evicted_v)``.
+    and ``b`` into ring slot ``slot`` of U/V for samples where ``upd``.
+    Returns ``(new_u, new_v, evicted_u, evicted_v)``.
     """
     impl = _resolve(impl)
     if impl == "ref":
         return ref.lowrank_append_ref(u, v, s, hy, b, inv_den, slot, upd)
-    m, bsz = u.shape[0], u.shape[1]
-    feat_shape = u.shape[2:]
-    flat = lambda a, lead: a.reshape(lead + (-1,))
-    new_u, new_v, ev_u, ev_v = lowrank_append_pallas(
-        flat(u, (m, bsz)), flat(v, (m, bsz)), flat(s, (bsz,)),
-        flat(hy, (bsz,)), flat(b, (bsz,)), inv_den,
-        slot.astype(jnp.int32), upd,
-        interpret=(impl == "pallas_interpret"),
-    )
-    unflat = lambda a, lead: a.reshape(lead + feat_shape)
-    return (unflat(new_u, (m, bsz)), unflat(new_v, (m, bsz)),
-            unflat(ev_u, (bsz,)), unflat(ev_v, (bsz,)))
+
+    def local(u, v, s, hy, b, inv_den, slot, upd):
+        m, bsz = u.shape[0], u.shape[1]
+        flat = lambda a, lead: a.reshape(lead + (-1,))
+        new_u, new_v, ev_u, ev_v = lowrank_append_pallas(
+            flat(u, (m, bsz)), flat(v, (m, bsz)), flat(s, (bsz,)),
+            flat(hy, (bsz,)), flat(b, (bsz,)), inv_den,
+            slot.astype(jnp.int32), upd,
+            interpret=(impl == "pallas_interpret"),
+        )
+        return (new_u.reshape(u.shape), new_v.reshape(v.shape),
+                ev_u.reshape(s.shape), ev_v.reshape(s.shape))
+
+    ring, row = _names(u.ndim, {1: "batch"}), _names(s.ndim, {0: "batch"})
+    vec = ("batch",)
+    return _on_mesh(local, (ring, ring, row, row, row, vec, vec, vec),
+                    [ring, ring, row, row])(
+        u, v, s, hy, b, inv_den, slot, upd)
 
 
 def broyden_step(u, v, g_new, s, hg_old, alpha, mask, slot, active, eps,
                  impl: Impl | None = None):
-    """The whole Broyden iteration's memory work in ONE kernel launch: the
-    fused K-RHS apply (``H @ g_new``, ``H^T @ s``), the denominator
-    ``s^T H y`` and the guarded ring append.  ``hg_old`` is the carried
+    """The whole Broyden iteration's memory work: ONE kernel launch and one
+    U/V pass computes ``H @ g_new``, ``H^T @ s`` and the slot's current
+    rows; the denominator ``s^T H y``, the guarded pair and the slot-row
+    write are O(B·D) XLA work on its outputs.  ``hg_old`` is the carried
     ``H @ g_old`` (so ``H y`` falls out by linearity).  Counts as exactly
-    one stream call — one fused U/V pass per solver iteration, write
-    included.
+    one stream call.
 
     Returns ``(new_u, new_v, hg_new, b, den, ev_u, ev_v)``; see
     ``kernels/ref.broyden_step_ref`` for the per-output contract.
@@ -320,21 +346,37 @@ def broyden_step(u, v, g_new, s, hg_old, alpha, mask, slot, active, eps,
     if impl == "ref":
         return ref.broyden_step_ref(u, v, g_new, s, hg_old, alpha, mask,
                                     slot, active, eps)
-    m, bsz = u.shape[0], u.shape[1]
-    feat_shape = u.shape[2:]
-    flat = lambda a, lead: a.reshape(lead + (-1,))
-    u2, v2 = flat(u, (m, bsz)), flat(v, (m, bsz))
-    u2, v2, mask = _pad_memory_axis(u2, v2, mask)
-    new_u, new_v, hg_new, b, den, ev_u, ev_v = broyden_step_pallas(
-        u2, v2, flat(g_new, (bsz,)), flat(s, (bsz,)), flat(hg_old, (bsz,)),
-        alpha, mask, slot.astype(jnp.int32),
-        jnp.asarray(active, jnp.float32), eps=float(eps),
-        interpret=(impl == "pallas_interpret"),
-    )
-    unflat = lambda a, lead: a.reshape(lead + feat_shape)
-    return (unflat(new_u[:m], (m, bsz)), unflat(new_v[:m], (m, bsz)),
-            unflat(hg_new, (bsz,)), unflat(b, (bsz,)), den,
-            unflat(ev_u, (bsz,)), unflat(ev_v, (bsz,)))
+
+    def local(u, v, g_new, s, hg_old, alpha, mask, slot, active):
+        m, bsz = u.shape[0], u.shape[1]
+        flat = lambda a, lead: a.reshape(lead + (-1,))
+        slot = slot.astype(jnp.int32)
+        u2, v2, mask2 = _pad_memory_axis(flat(u, (m, bsz)), flat(v, (m, bsz)),
+                                         mask)
+        s2 = flat(s, (bsz,)).astype(jnp.float32)
+        hg_new, b, ev_u, ev_v = broyden_step_pallas(
+            u2, v2, flat(g_new, (bsz,)), s2, alpha, mask2, slot,
+            interpret=(impl == "pallas_interpret"))
+        hy = hg_new - flat(hg_old, (bsz,)).astype(jnp.float32)
+        den = jnp.sum(s2 * hy, axis=1)
+        safe = jnp.abs(den) > eps
+        upd = (active > 0.5) & safe
+        inv_den = jnp.where(safe, 1.0 / jnp.where(safe, den, 1.0), 0.0)
+        a = (s2 - hy) * inv_den[:, None]
+        row_u = jnp.where(upd[:, None], a.astype(u.dtype), ev_u)
+        row_v = jnp.where(upd[:, None], b.astype(v.dtype), ev_v)
+        new_u, new_v = _write_slot_rows(
+            u, v, slot, row_u.reshape(s.shape), row_v.reshape(s.shape))
+        return (new_u, new_v, hg_new.reshape(s.shape), b.reshape(s.shape),
+                den, ev_u.reshape(s.shape), ev_v.reshape(s.shape))
+
+    ring, row = _names(u.ndim, {1: "batch"}), _names(s.ndim, {0: "batch"})
+    vec = ("batch",)
+    return _on_mesh(
+        local, (ring, ring, row, row, row, None, ring[:2], vec, vec),
+        [ring, ring, row, row, vec, row, row])(
+        u, v, g_new, s, hg_old, jnp.asarray(alpha, jnp.float32), mask, slot,
+        jnp.asarray(active, jnp.float32))
 
 
 # ---------------------------------------------------------------------------
@@ -342,14 +384,36 @@ def broyden_step(u, v, g_new, s, hg_old, alpha, mask, slot, active, eps,
 # ---------------------------------------------------------------------------
 
 
+def _heads_axis(num_heads: int, num_kv_heads: int) -> str | None:
+    """The logical name attention kernels may split heads over: only when q
+    and kv heads share one layout that divides both (each device then keeps
+    whole GQA groups)."""
+    ctx = _KERNEL_CTX.get()
+    if ctx is None:
+        return None
+    phys = ctx.rules.physical("heads_act")
+    n = ctx.axis_size("heads_act")
+    if (phys is None or phys != ctx.rules.physical("kv_heads_act")
+            or num_heads % n or num_kv_heads % n):
+        return None
+    return "heads_act"
+
+
 def _attention_fwd_impl(q, k, v, kv_length, causal, scale, impl):
     if impl == "ref":
         return ref.attention_ref(q, k, v, causal=causal, kv_length=kv_length,
                                  scale=scale)
-    return flash_attention_pallas(
-        q, k, v, kv_length, causal=causal, scale=scale,
-        interpret=(impl == "pallas_interpret"),
-    )
+    if kv_length is None:
+        kv_length = jnp.full((q.shape[0],), k.shape[1], jnp.int32)
+
+    def local(q, k, v, kv_length):
+        return flash_attention_pallas(
+            q, k, v, kv_length, causal=causal, scale=scale,
+            interpret=(impl == "pallas_interpret"))
+
+    heads = _names(4, {0: "batch", 2: _heads_axis(q.shape[2], k.shape[2])})
+    return _on_mesh(local, (heads, heads, heads, ("batch",)), heads)(
+        q, k, v, kv_length)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
@@ -422,9 +486,19 @@ def decode_attention(
     impl = _resolve(impl)
     if impl == "ref":
         return ref.decode_attention_ref(q, k, v, kv_length, scale=scale)
-    return decode_attention_pallas(
-        q, k, v, kv_length, scale=scale, interpret=(impl == "pallas_interpret")
-    )
+
+    def local(q, k, v, kv_length):
+        return decode_attention_pallas(
+            q, k, v, kv_length, scale=scale,
+            interpret=(impl == "pallas_interpret"))
+
+    # the cache's sequence axis stays whole: a split softmax would need a
+    # cross-device combine
+    heads = _heads_axis(q.shape[1], k.shape[2])
+    q_names = _names(3, {0: "batch", 1: heads})
+    kv_names = _names(4, {0: "batch", 2: heads})
+    return _on_mesh(local, (q_names, kv_names, kv_names, ("batch",)),
+                    q_names)(q, k, v, kv_length)
 
 
 # ---------------------------------------------------------------------------
@@ -436,7 +510,13 @@ def decode_attention(
 def _rmsnorm(x, w, eps, impl):
     if impl == "ref":
         return ref.rmsnorm_ref(x, w, eps)
-    return rmsnorm_pallas(x, w, eps=eps, interpret=(impl == "pallas_interpret"))
+
+    def local(x, w):
+        return rmsnorm_pallas(x, w, eps=eps,
+                              interpret=(impl == "pallas_interpret"))
+
+    rows = _names(x.ndim, {0: "batch"})
+    return _on_mesh(local, (rows, None), rows)(x, w)
 
 
 def _rmsnorm_fwd(x, w, eps, impl):

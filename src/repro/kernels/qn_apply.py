@@ -4,58 +4,53 @@ quasi-Newton inverse ``H = alpha*I + U^T V`` — and maintaining it.
     H @ x   = alpha * x[b] + sum_i mask[i,b] * u[i,b,:] * <v[i,b,:], x[b,:]>
     H^T @ x = alpha * x[b] + sum_i mask[i,b] * v[i,b,:] * <u[i,b,:], x[b,:]>
 
-This op runs up to three times per Broyden iteration in the forward pass
-(direction, matvec and rmatvec of the Sherman–Morrison update) and exactly
-once in the SHINE backward pass — it IS the "shared inverse estimate". It is
-memory-bound: the U/V streams dominate, so the fused multi-vector kernel
-amortizes ONE stream over U/V across a whole stack of right-hand sides.
+This op runs in every Broyden iteration of the forward pass and once in the
+SHINE backward pass — it IS the "shared inverse estimate".  It is
+memory-bound: the U/V streams dominate, so one kernel amortizes ONE stream
+over U/V across a whole stack of right-hand sides.
 
 Kernels in this module:
 
-``qn_apply_pallas``        single RHS (kept for the backward pass / K=1).
 ``qn_apply_multi_pallas``  K stacked RHS, each independently applying H or
-                           H^T (static ``transpose`` flags).  Two phases as
-                           two pallas_calls sharing the d-tile stream:
-                             1. coefficient phase: accumulate a (K, m) block
-                                in a VMEM-resident output across the d-grid
-                                (TPU grids execute sequentially, which makes
-                                cross-step accumulation sound);
-                             2. apply phase: emit all K output tiles per
-                                U/V tile.
-                           A buffer is only streamed by a phase that needs
-                           it: with uniform flags each phase touches exactly
-                           one of U/V, so K same-direction applications cost
-                           one U stream + one V stream total (K x fewer
-                           bytes); mixed flags cost two of each (1.5 x fewer
-                           for the fused Broyden step).
-``lowrank_append_pallas``  fused Broyden ring-buffer update: computes the
+                           H^T (static ``transpose`` flags), in one
+                           pallas_call over the grid ``(batch blocks, phase,
+                           d-tiles)``: phase 0 accumulates the (K, m)
+                           coefficients of a batch block in VMEM scratch
+                           across its d-tiles, phase 1 emits the K output
+                           tiles.  A buffer is streamed only in a phase that
+                           needs it (its index map stands still in the other
+                           phase, so the pipeline does not refetch it): with
+                           uniform flags each phase reads one of U/V (K
+                           same-direction applications cost one U plus one V
+                           stream), mixed flags read both in both phases.
+                           The single-RHS ``qn_apply`` is its K = 1 case.
+``lowrank_append_pallas``  Broyden ring-buffer update: computes the
                            rank-one pair a_n = (s - Hy)/den in-kernel and
-                           writes ONLY the target ring slot via scalar-
-                           prefetched row indexing + input/output aliasing —
-                           no gather/scatter round-trip over the (m, B, D)
-                           buffers — and returns the evicted pair so the
-                           solver can rank-one-correct carried products.
-``broyden_step_pallas``    the whole Broyden iteration's memory work as ONE
-                           pallas_call: the K-RHS apply (H @ g_new, H^T @ s)
-                           AND the ring append in a single launch and a
-                           single U/V pass including the write.  The trick
-                           is the denominator: s^T H y is needed before the
-                           append can be formed, but it decomposes as
-                           alpha*(s.g_new) + sum_i mask_i (v_i.g_new)(u_i.s)
-                           - s.Hg_old — exactly the coefficient-phase
-                           products plus two cheap vector dots, so no third
-                           U/V stream is required.  Phase 0 accumulates
-                           coefficients and the denominator (and writes the
-                           OLD slot row into the aliased row outputs, making
-                           the write-backs value-identical no-ops); phase 1
-                           emits H @ g_new, H^T @ s and the guarded slot
-                           write.  Inputs may be stored bf16: both phases
-                           upcast tiles on read and accumulate in f32 VMEM.
+                           writes it into each row's ring slot in place
+                           (input/output aliasing), returning the evicted
+                           pair so the solver can rank-one-correct carried
+                           products.  It reads and writes every U/V tile
+                           once.
+``broyden_step_pallas``    one Broyden iteration's U/V work as ONE
+                           pallas_call and one mixed-flag U/V pass: H @ g_new
+                           and H^T @ s, plus the ring slot's previous rows
+                           (picked out of the phase-0 tiles).  The
+                           denominator s^T H y, the guarded pair and the ring
+                           write are O(B·D) and run in XLA on the kernel's
+                           outputs; the write is a row scatter into the
+                           loop-carried ring, not a second ring pass.
 
-MXU alignment: the d-tile is clamped to a multiple of 128 lanes and the
-feature axis is zero-padded up to the lane boundary (never a ragged
-``min(block_d, dim)`` tile); the m axis is zero-padded to a multiple of 8
-sublanes by the wrapper in ops.py.
+Tiling (what the TPU lowering accepts): the last two block dims must be
+multiples of (8, 128) or the whole array dims.  The ring is ``(m, B, D)``,
+so a tile is ``(m, bb, block_d)`` with ``bb`` = 8 batch rows when B is a
+multiple of 8 and all of B otherwise; per-row scalars (slot, 1/den, update
+flags) ride as ``(B, 1)`` columns and the mask as ``(m, B, 1)``, so they
+broadcast against tiles along lanes; ``alpha`` sits in SMEM.  Products are
+lane reductions and leading-axis sums on the VPU — the op is memory-bound,
+so nothing is gained by feeding the MXU m-wide matrices.  The d-tile is
+clamped to a multiple of 128 lanes and the feature axis zero-padded up to
+it; the m axis is zero-padded to a multiple of 8 by the wrapper in ops.py.
+Tiles are upcast to f32 on read, so the ring may be stored bf16.
 """
 
 from __future__ import annotations
@@ -68,6 +63,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
+_SUBLANES = 8
 
 
 def _round_up(n: int, mult: int) -> int:
@@ -89,154 +85,133 @@ def _pad_features(block_d: int, dim: int, *arrays):
     return (block_d,) + arrays
 
 
-# ---------------------------------------------------------------------------
-# Single-RHS apply (K = 1)
-# ---------------------------------------------------------------------------
+def _batch_block(bsz: int) -> int:
+    """Rows per batch block: a sublane multiple, or the whole batch."""
+    return _SUBLANES if bsz % _SUBLANES == 0 else bsz
 
 
-def _coeff_kernel(v_ref, x_ref, mask_ref, coeff_ref):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        coeff_ref[...] = jnp.zeros_like(coeff_ref)
-
-    v = v_ref[:, 0, :].astype(jnp.float32)       # (m, blk_d)
-    x = x_ref[0, :].astype(jnp.float32)          # (blk_d,)
-    partial = v @ x                              # (m,)
-    coeff_ref[0, :] += partial * mask_ref[:, 0].astype(jnp.float32)
+def _tile_width(block_d: int, m: int, bb: int) -> int:
+    """Narrow the d-tile when a whole-batch block is wide, so an ``(m, bb,
+    block_d)`` tile stays within the 8 x 8 x 512 elements of the default
+    (and its f32 copies within the scoped VMEM)."""
+    cap = (_SUBLANES * _SUBLANES * 512) // (m * bb)
+    return max(_LANES, min(block_d, cap // _LANES * _LANES))
 
 
-def _apply_kernel(u_ref, x_ref, coeff_ref, alpha_ref, out_ref):
-    u = u_ref[:, 0, :].astype(jnp.float32)       # (m, blk_d)
-    x = x_ref[0, :].astype(jnp.float32)          # (blk_d,)
-    c = coeff_ref[0, :]                          # (m,) f32
-    alpha = alpha_ref[0]
-    out_ref[0, :] = (alpha * x + c @ u).astype(out_ref.dtype)
+def _smem_scalar(x) -> jax.Array:
+    return jnp.reshape(jnp.asarray(x, jnp.float32), (1,))
 
 
-@functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
-def qn_apply_pallas(
-    u: jax.Array,      # (m, B, D)
-    v: jax.Array,      # (m, B, D)
-    x: jax.Array,      # (B, D)
-    alpha: jax.Array,  # scalar f32
-    mask: jax.Array,   # (m, B) f32
-    *,
-    block_d: int = 512,
-    interpret: bool = False,
-) -> jax.Array:
-    m, bsz, dim = u.shape
-    block_d, u, v, x = _pad_features(block_d, dim, u, v, x)
-    dim_p = x.shape[-1]
-    nd = dim_p // block_d
-    alpha_arr = jnp.broadcast_to(jnp.asarray(alpha, jnp.float32), (1,))
-
-    coeff = pl.pallas_call(
-        _coeff_kernel,
-        grid=(bsz, nd),
-        in_specs=[
-            pl.BlockSpec((m, 1, block_d), lambda b, j: (0, b, j)),
-            pl.BlockSpec((1, block_d), lambda b, j: (b, j)),
-            pl.BlockSpec((m, 1), lambda b, j: (0, b)),
-        ],
-        out_specs=pl.BlockSpec((1, m), lambda b, j: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((bsz, m), jnp.float32),
-        interpret=interpret,
-    )(v, x, mask)
-
-    out = pl.pallas_call(
-        _apply_kernel,
-        grid=(bsz, nd),
-        in_specs=[
-            pl.BlockSpec((m, 1, block_d), lambda b, j: (0, b, j)),
-            pl.BlockSpec((1, block_d), lambda b, j: (b, j)),
-            pl.BlockSpec((1, m), lambda b, j: (b, 0)),
-            pl.BlockSpec((1,), lambda b, j: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, block_d), lambda b, j: (b, j)),
-        out_shape=jax.ShapeDtypeStruct((bsz, dim_p), x.dtype),
-        interpret=interpret,
-    )(u, x, coeff, alpha_arr)
-
-    return out[:, :dim]
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 # ---------------------------------------------------------------------------
-# Multi-RHS apply: K right-hand sides, per-RHS H vs H^T, one U/V stream
+# K-RHS apply (and the fused Broyden step): grid (batch block, phase, d-tile)
 # ---------------------------------------------------------------------------
 
 
-def _contract_d(x, w):
-    # (K, blk) x (m, blk) -> (K, m), f32 accumulation
-    return jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
+def _make_qn_kernel(transpose: tuple[bool, ...], evict: bool):
+    """Phase 0 accumulates ``coeff[k] = <w_k, x_k>`` (w = U for an H^T row,
+    V for an H row) over the d-tiles; phase 1 writes ``alpha*x_k + sum_i
+    mask_i coeff[k]_i w'_i`` (w' the other buffer).  With ``evict`` the
+    phase-0 tiles also yield ring slot ``slot[b]``'s current rows."""
 
+    def kernel(alpha_ref, u_ref, v_ref, xs_ref, mask_ref, *rest):
+        if evict:
+            slot_ref, out_ref, ev_u_ref, ev_v_ref, coeff_ref = rest
+        else:
+            out_ref, coeff_ref = rest
+        ph = pl.program_id(1)
+        j = pl.program_id(2)
 
-def _contract_m(c, w):
-    # (K, m) x (m, blk) -> (K, blk), f32 accumulation
-    return jax.lax.dot_general(
-        c, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-
-
-def _make_coeff_multi_kernel(transpose: tuple[bool, ...]):
-    # ``transpose`` is static, so the kernel specializes: uniform flags bind
-    # a single buffer; mixed flags bind both plus a (K, 1) selector input.
-    any_t, any_f = any(transpose), not all(transpose)
-
-    def kernel(*refs):
-        refs = list(refs)
-        u_ref = refs.pop(0) if any_t else None
-        v_ref = refs.pop(0) if any_f else None
-        tsel_ref = refs.pop(0) if (any_t and any_f) else None
-        x_ref, coeff_ref = refs
-        j = pl.program_id(1)
-
-        @pl.when(j == 0)
+        @pl.when((ph == 0) & (j == 0))
         def _init():
             coeff_ref[...] = jnp.zeros_like(coeff_ref)
 
-        x = x_ref[:, 0, :].astype(jnp.float32)                 # (K, blk_d)
-        if any_t and any_f:
-            pu = _contract_d(x, u_ref[:, 0, :].astype(jnp.float32))
-            pv = _contract_d(x, v_ref[:, 0, :].astype(jnp.float32))
-            tsel = tsel_ref[:, :]                              # (K, 1) f32
-            partial = tsel * pu + (1.0 - tsel) * pv            # (K, m)
-        elif any_t:
-            partial = _contract_d(x, u_ref[:, 0, :].astype(jnp.float32))
-        else:
-            partial = _contract_d(x, v_ref[:, 0, :].astype(jnp.float32))
-        coeff_ref[0, :, :] += partial
+        @pl.when(ph == 0)
+        def _coefficients():
+            for k, t in enumerate(transpose):
+                w = (u_ref if t else v_ref)[...].astype(jnp.float32)
+                x = xs_ref[k].astype(jnp.float32)             # (bb, blk)
+                coeff_ref[k] += jnp.sum(w * x[None], axis=-1, keepdims=True)
+            if evict:
+                u = u_ref[...]
+                rows = jax.lax.broadcasted_iota(jnp.int32, (u.shape[0],)
+                                                + u.shape[1:2] + (1,), 0)
+                hot = rows == slot_ref[...][None]             # (m, bb, 1)
+                ev_u_ref[...] = jnp.sum(
+                    jnp.where(hot, u.astype(jnp.float32), 0.0),
+                    axis=0).astype(ev_u_ref.dtype)
+                ev_v_ref[...] = jnp.sum(
+                    jnp.where(hot, v_ref[...].astype(jnp.float32), 0.0),
+                    axis=0).astype(ev_v_ref.dtype)
+
+        @pl.when(ph == 1)
+        def _apply():
+            maskv = mask_ref[...]                             # (m, bb, 1)
+            alpha = alpha_ref[0]
+            for k, t in enumerate(transpose):
+                w = (v_ref if t else u_ref)[...].astype(jnp.float32)
+                c = coeff_ref[k] * maskv
+                out_ref[k] = (alpha * xs_ref[k].astype(jnp.float32)
+                              + jnp.sum(c * w, axis=0)).astype(out_ref.dtype)
 
     return kernel
 
 
-def _make_apply_multi_kernel(transpose: tuple[bool, ...]):
+def _qn_call(u, v, xs, alpha, mask, transpose, *, slot=None, block_d,
+             interpret):
+    """Shared pallas_call of the K-RHS apply; ``slot`` adds the evicted-row
+    outputs.  Returns ``out`` or ``(out, ev_u, ev_v)`` at the input width."""
+    m, bsz, dim = u.shape
+    kk = xs.shape[0]
     any_t, any_f = any(transpose), not all(transpose)
+    bb = _batch_block(bsz)
+    block_d, u, v, xs = _pad_features(_tile_width(block_d, m, bb), dim,
+                                      u, v, xs)
+    dim_p = xs.shape[-1]
+    nd = dim_p // block_d
+    evict = slot is not None
 
-    def kernel(*refs):
-        refs = list(refs)
-        u_ref = refs.pop(0) if any_f else None
-        v_ref = refs.pop(0) if any_t else None
-        tsel_ref = refs.pop(0) if (any_t and any_f) else None
-        x_ref, coeff_ref, mask_ref, alpha_ref, out_ref = refs
+    def tile_map(phase0: bool, phase1: bool):
+        # stand still (no refetch) in the phase that does not read the tile
+        if phase0 and phase1:
+            return lambda b, ph, j: (0, b, j)
+        if phase1:
+            return lambda b, ph, j: (0, b, j * ph)
+        return lambda b, ph, j: (0, b, j * (1 - ph) + (nd - 1) * ph)
 
-        x = x_ref[:, 0, :].astype(jnp.float32)                 # (K, blk_d)
-        c = coeff_ref[0, :, :] * mask_ref[:, 0][None, :]       # (K, m) f32
-        if any_t and any_f:
-            ou = _contract_m(c, u_ref[:, 0, :].astype(jnp.float32))
-            ov = _contract_m(c, v_ref[:, 0, :].astype(jnp.float32))
-            tsel = tsel_ref[:, :]                              # (K, 1) f32
-            term = tsel * ov + (1.0 - tsel) * ou               # (K, blk_d)
-        elif any_t:
-            term = _contract_m(c, v_ref[:, 0, :].astype(jnp.float32))
-        else:
-            term = _contract_m(c, u_ref[:, 0, :].astype(jnp.float32))
-        out_ref[:, 0, :] = (alpha_ref[0] * x + term).astype(out_ref.dtype)
-
-    return kernel
+    tile = (m, bb, block_d)
+    in_specs = [
+        _SMEM,
+        pl.BlockSpec(tile, tile_map(any_t, any_f)),           # u
+        pl.BlockSpec(tile, tile_map(any_f, any_t)),           # v
+        pl.BlockSpec((kk, bb, block_d), lambda b, ph, j: (0, b, j)),
+        pl.BlockSpec((m, bb, 1), lambda b, ph, j: (0, b, 0)),
+    ]
+    out_specs = [pl.BlockSpec((kk, bb, block_d),
+                              lambda b, ph, j: (0, b, j * ph))]
+    out_shape = [jax.ShapeDtypeStruct((kk, bsz, dim_p), xs.dtype)]
+    args = [_smem_scalar(alpha), u, v, xs, mask[..., None]]
+    if evict:
+        in_specs.append(pl.BlockSpec((bb, 1), lambda b, ph, j: (b, 0)))
+        args.append(slot.astype(jnp.int32)[:, None])
+        row = pl.BlockSpec((bb, block_d),
+                           lambda b, ph, j: (b, j * (1 - ph) + (nd - 1) * ph))
+        out_specs += [row, row]
+        out_shape += [jax.ShapeDtypeStruct((bsz, dim_p), u.dtype),
+                      jax.ShapeDtypeStruct((bsz, dim_p), v.dtype)]
+    outs = pl.pallas_call(
+        _make_qn_kernel(tuple(transpose), evict),
+        grid=(bsz // bb, 2, nd),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((kk, m, bb, 1), jnp.float32)],
+        interpret=interpret,
+    )(*args)
+    outs = [o[..., :dim] for o in outs]
+    return outs[0] if not evict else tuple(outs)
 
 
 @functools.partial(jax.jit, static_argnames=("transpose", "block_d",
@@ -252,91 +227,32 @@ def qn_apply_multi_pallas(
     block_d: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
-    """out[k] = (H^T if transpose[k] else H) @ xs[k], one stream over U/V.
-
-    The coefficient phase accumulates the (K, m) coefficient block in a
-    VMEM-resident output across the d-grid; the apply phase emits all K
-    output tiles per U/V tile.  Each phase only streams the buffer(s) its
-    flag mix requires.
-    """
-    m, bsz, dim = u.shape
-    kk = xs.shape[0]
-    assert len(transpose) == kk, (len(transpose), kk)
-    any_t, any_f = any(transpose), not all(transpose)
-    block_d, u, v, xs = _pad_features(block_d, dim, u, v, xs)
-    dim_p = xs.shape[-1]
-    nd = dim_p // block_d
-    alpha_arr = jnp.broadcast_to(jnp.asarray(alpha, jnp.float32), (1,))
-
-    uv_spec = pl.BlockSpec((m, 1, block_d), lambda b, j: (0, b, j))
-    xs_spec = pl.BlockSpec((kk, 1, block_d), lambda b, j: (0, b, j))
-    tsel_spec = pl.BlockSpec((kk, 1), lambda b, j: (0, 0))
-    tsel = jnp.asarray(transpose, jnp.float32)[:, None]        # (K, 1)
-
-    coeff_ins, coeff_args = [], []
-    if any_t:
-        coeff_ins.append(uv_spec)
-        coeff_args.append(u)
-    if any_f:
-        coeff_ins.append(uv_spec)
-        coeff_args.append(v)
-    if any_t and any_f:
-        coeff_ins.append(tsel_spec)
-        coeff_args.append(tsel)
-    coeff = pl.pallas_call(
-        _make_coeff_multi_kernel(transpose),
-        grid=(bsz, nd),
-        in_specs=coeff_ins + [xs_spec],
-        out_specs=pl.BlockSpec((1, kk, m), lambda b, j: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((bsz, kk, m), jnp.float32),
-        interpret=interpret,
-    )(*coeff_args, xs)
-
-    apply_ins, apply_args = [], []
-    if any_f:
-        apply_ins.append(uv_spec)
-        apply_args.append(u)
-    if any_t:
-        apply_ins.append(uv_spec)
-        apply_args.append(v)
-    if any_t and any_f:
-        apply_ins.append(tsel_spec)
-        apply_args.append(tsel)
-    out = pl.pallas_call(
-        _make_apply_multi_kernel(transpose),
-        grid=(bsz, nd),
-        in_specs=apply_ins + [
-            xs_spec,
-            pl.BlockSpec((1, kk, m), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((m, 1), lambda b, j: (0, b)),
-            pl.BlockSpec((1,), lambda b, j: (0,)),
-        ],
-        out_specs=pl.BlockSpec((kk, 1, block_d), lambda b, j: (0, b, j)),
-        out_shape=jax.ShapeDtypeStruct((kk, bsz, dim_p), xs.dtype),
-        interpret=interpret,
-    )(*apply_args, xs, coeff, mask, alpha_arr)
-
-    return out[:, :, :dim]
+    """out[k] = (H^T if transpose[k] else H) @ xs[k], one stream over U/V."""
+    assert len(transpose) == xs.shape[0], (len(transpose), xs.shape[0])
+    return _qn_call(u, v, xs, alpha, mask, transpose, block_d=block_d,
+                    interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
-# Fused Broyden ring-buffer update
+# Broyden ring-buffer update (in place, one read + one write of the ring)
 # ---------------------------------------------------------------------------
 
 
-def _append_kernel(slot_ref, u_ref, v_ref, s_ref, hy_ref, b_ref, den_ref,
+def _append_kernel(u_ref, v_ref, s_ref, hy_ref, b_ref, inv_den_ref, slot_ref,
                    upd_ref, out_u_ref, out_v_ref, ev_u_ref, ev_v_ref):
-    del slot_ref  # consumed by the index maps (scalar prefetch)
-    old_u = u_ref[0, 0, :]
-    old_v = v_ref[0, 0, :]
-    ev_u_ref[0, :] = old_u
-    ev_v_ref[0, :] = old_v
-    upd = upd_ref[0] > 0.5
-    a = (s_ref[0, :].astype(jnp.float32)
-         - hy_ref[0, :].astype(jnp.float32)) * den_ref[0]
-    out_u_ref[0, 0, :] = jnp.where(upd, a.astype(out_u_ref.dtype), old_u)
-    out_v_ref[0, 0, :] = jnp.where(
-        upd, b_ref[0, :].astype(out_v_ref.dtype), old_v)
+    u = u_ref[...]
+    v = v_ref[...]
+    rows = jax.lax.broadcasted_iota(jnp.int32, u.shape[:2] + (1,), 0)
+    hot = rows == slot_ref[...][None]                         # (m, bb, 1)
+    ev_u_ref[...] = jnp.sum(jnp.where(hot, u.astype(jnp.float32), 0.0),
+                            axis=0).astype(ev_u_ref.dtype)
+    ev_v_ref[...] = jnp.sum(jnp.where(hot, v.astype(jnp.float32), 0.0),
+                            axis=0).astype(ev_v_ref.dtype)
+    write = hot & (upd_ref[...][None] > 0.5)
+    a = ((s_ref[...].astype(jnp.float32) - hy_ref[...].astype(jnp.float32))
+         * inv_den_ref[...])                                  # (bb, blk)
+    out_u_ref[...] = jnp.where(write, a.astype(u.dtype)[None], u)
+    out_v_ref[...] = jnp.where(write, b_ref[...].astype(v.dtype)[None], v)
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
@@ -354,202 +270,66 @@ def lowrank_append_pallas(
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Write the Broyden pair ``a = (s - Hy) * inv_den``, ``b`` into ring
-    slot ``slot[bb]`` of U/V in place, touching ONLY that (1, 1, D) row per
-    sample (scalar-prefetched row indexing + input/output aliasing — no
-    gather/scatter round-trip over the (m, B, D) buffers).
+    slot ``slot[bb]`` of U/V in place (input/output aliasing).
 
     Returns ``(new_u, new_v, evicted_u, evicted_v)``; the evicted row is the
     slot's previous content, letting callers rank-one-correct carried
     products like ``H @ g`` when the ring wraps.
     """
     m, bsz, dim = u.shape
-    block_d, u, v, s, hy, b = _pad_features(block_d, dim, u, v, s, hy, b)
+    bb = _batch_block(bsz)
+    block_d, u, v, s, hy, b = _pad_features(_tile_width(block_d, m, bb), dim,
+                                            u, v, s, hy, b)
     dim_p = u.shape[-1]
-    nd = dim_p // block_d
 
-    row_spec = pl.BlockSpec((1, 1, block_d), lambda bb, j, sl: (sl[bb], bb, j))
-    vec_spec = pl.BlockSpec((1, block_d), lambda bb, j, sl: (bb, j))
-    per_b = pl.BlockSpec((1,), lambda bb, j, sl: (bb,))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(bsz, nd),
-        in_specs=[row_spec, row_spec, vec_spec, vec_spec, vec_spec,
-                  per_b, per_b],
-        out_specs=[row_spec, row_spec, vec_spec, vec_spec],
-    )
+    tile = pl.BlockSpec((m, bb, block_d), lambda i, j: (0, i, j))
+    vec = pl.BlockSpec((bb, block_d), lambda i, j: (i, j))
+    col = pl.BlockSpec((bb, 1), lambda i, j: (i, 0))
     new_u, new_v, ev_u, ev_v = pl.pallas_call(
         _append_kernel,
-        grid_spec=grid_spec,
+        grid=(bsz // bb, dim_p // block_d),
+        in_specs=[tile, tile, vec, vec, vec, col, col, col],
+        out_specs=[tile, tile, vec, vec],
         out_shape=[
             jax.ShapeDtypeStruct(u.shape, u.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
             jax.ShapeDtypeStruct((bsz, dim_p), u.dtype),
             jax.ShapeDtypeStruct((bsz, dim_p), v.dtype),
         ],
-        # aliasing indices count the scalar-prefetch operand: slot=0, u=1, v=2
-        input_output_aliases={1: 0, 2: 1},
+        input_output_aliases={0: 0, 1: 1},
         interpret=interpret,
-    )(slot, u, v, s, hy, b, inv_den.astype(jnp.float32),
-      upd.astype(jnp.float32))
-
-    if dim_p != dim:
-        new_u, new_v = new_u[..., :dim], new_v[..., :dim]
-        ev_u, ev_v = ev_u[..., :dim], ev_v[..., :dim]
-    return new_u, new_v, ev_u, ev_v
+    )(u, v, s, hy, b, inv_den.astype(jnp.float32)[:, None],
+      slot.astype(jnp.int32)[:, None], upd.astype(jnp.float32)[:, None])
+    return (new_u[..., :dim], new_v[..., :dim], ev_u[..., :dim],
+            ev_v[..., :dim])
 
 
 # ---------------------------------------------------------------------------
-# Fused Broyden step: apply + ring append in one launch, one U/V pass
+# Fused Broyden step: one launch and one U/V pass per iteration
 # ---------------------------------------------------------------------------
 
 
-def _make_broyden_step_kernel(eps: float, nd: int):
-    def kernel(slot_ref, u_ref, v_ref, g_ref, s_ref, hg_ref, mask_ref,
-               alpha_ref, active_ref, new_u_ref, new_v_ref, hg_new_ref,
-               b_ref, ev_u_ref, ev_v_ref, coeff_ref, den_ref):
-        bb = pl.program_id(0)
-        ph = pl.program_id(1)
-        j = pl.program_id(2)
-        sl = slot_ref[bb]
-        u_t = u_ref[:, 0, :].astype(jnp.float32)            # (m, blk)
-        v_t = v_ref[:, 0, :].astype(jnp.float32)
-        rows = jax.lax.broadcasted_iota(jnp.int32, u_t.shape, 0)
-        is_slot = rows == sl
-        old_u = jnp.sum(jnp.where(is_slot, u_t, 0.0), axis=0)   # (blk,)
-        old_v = jnp.sum(jnp.where(is_slot, v_t, 0.0), axis=0)
-        gj = g_ref[0, :]
-        sj = s_ref[0, :]
-
-        @pl.when((ph == 0) & (j == 0))
-        def _init():
-            coeff_ref[...] = jnp.zeros_like(coeff_ref)
-            den_ref[...] = jnp.zeros_like(den_ref)
-
-        @pl.when(ph == 0)
-        def _coeff_phase():
-            coeff_ref[0, 0, :] += v_t @ gj                  # v_i . g_new
-            coeff_ref[0, 1, :] += u_t @ sj                  # u_i . s
-            den_ref[0, 0] += (alpha_ref[0] * jnp.sum(sj * gj)
-                              - jnp.sum(sj * hg_ref[0, :]))
-            # write the OLD row into the aliased row outputs so phase-0
-            # write-backs are value-identical no-ops against the u/v tiles
-            # phase 1 re-reads; this read doubles as the eviction path
-            new_u_ref[0, 0, :] = old_u.astype(new_u_ref.dtype)
-            new_v_ref[0, 0, :] = old_v.astype(new_v_ref.dtype)
-            ev_u_ref[0, :] = old_u.astype(ev_u_ref.dtype)
-            ev_v_ref[0, :] = old_v.astype(ev_v_ref.dtype)
-            hg_new_ref[0, :] = jnp.zeros_like(hg_new_ref[0, :])
-            b_ref[0, :] = jnp.zeros_like(b_ref[0, :])
-
-        @pl.when((ph == 0) & (j == nd - 1))
-        def _den_final():
-            # all d-tiles accumulated: fold in the rank-one part of
-            # den = alpha*(s.g_new) + sum_i mask_i (v_i.g)(u_i.s) - s.Hg_old
-            den_ref[0, 0] += jnp.sum(
-                mask_ref[:, 0] * coeff_ref[0, 0, :] * coeff_ref[0, 1, :])
-
-        @pl.when(ph == 1)
-        def _apply_phase():
-            maskv = mask_ref[:, 0]
-            cg = coeff_ref[0, 0, :] * maskv
-            cs = coeff_ref[0, 1, :] * maskv
-            alpha = alpha_ref[0]
-            hg_new_j = alpha * gj + cg @ u_t                # (blk,)
-            b_j = alpha * sj + cs @ v_t
-            den = den_ref[0, 0]
-            safe = jnp.abs(den) > eps
-            upd = safe & (active_ref[0] > 0.5)
-            inv_den = jnp.where(safe, 1.0 / jnp.where(safe, den, 1.0), 0.0)
-            hy_j = hg_new_j - hg_ref[0, :]
-            a_j = (sj - hy_j) * inv_den
-            hg_new_ref[0, :] = hg_new_j
-            b_ref[0, :] = b_j
-            ev_u_ref[0, :] = old_u.astype(ev_u_ref.dtype)
-            ev_v_ref[0, :] = old_v.astype(ev_v_ref.dtype)
-            new_u_ref[0, 0, :] = jnp.where(
-                upd, a_j.astype(new_u_ref.dtype),
-                old_u.astype(new_u_ref.dtype))
-            new_v_ref[0, 0, :] = jnp.where(
-                upd, b_j.astype(new_v_ref.dtype),
-                old_v.astype(new_v_ref.dtype))
-
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("eps", "block_d", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def broyden_step_pallas(
     u: jax.Array,        # (m, B, D) qN ring (storage dtype: f32 or bf16)
     v: jax.Array,        # (m, B, D)
     g_new: jax.Array,    # (B, D) residual at the new iterate
     s: jax.Array,        # (B, D) step z_new - z
-    hg_old: jax.Array,   # (B, D) carried H @ g_old
     alpha: jax.Array,    # scalar f32
     mask: jax.Array,     # (m, B) f32 validity of the PRE-update ring
-    slot: jax.Array,     # (B,) int32 ring slot to write
-    active: jax.Array,   # (B,) f32 1.0 where the sample still iterates
+    slot: jax.Array,     # (B,) int32 ring slot to be written
     *,
-    eps: float,
     block_d: int = 512,
     interpret: bool = False,
-) -> tuple[jax.Array, ...]:
-    """One Broyden iteration = one kernel launch and one U/V pass.
-
-    Grid ``(B, 2, nd)``: phase 0 streams the u/v tiles once, accumulating
-    the (2, m) coefficient block and the denominator ``s^T H y`` in
-    VMEM-resident f32 outputs; phase 1 streams them again to emit
-    ``H @ g_new`` / ``H^T @ s`` and writes the guarded rank-one pair into
-    ring slot ``slot[bb]`` via input/output aliasing.  Total U/V traffic is
-    the mixed-flag apply model (4·m·B·D·itemsize) — the append costs no
-    extra stream because the written row rides the aliased row output.
-
-    Returns ``(new_u, new_v, hg_new, b, den, ev_u, ev_v)``; ``hg_new``/``b``
-    are f32, ``den`` is (B,) f32, ``ev_u``/``ev_v`` (storage dtype) are the
-    slot's previous contents for the caller's carried-product correction.
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The U/V work of one Broyden iteration in one launch: returns
+    ``(hg_new, b, ev_u, ev_v)`` with ``hg_new = H @ g_new`` and ``b = H^T
+    s`` in f32 and ``ev_u/ev_v`` ring slot ``slot``'s current rows (storage
+    dtype).  U/V traffic is the mixed-flag apply model (4·m·B·D·itemsize);
+    the caller forms the pair and writes the slot rows (ops.broyden_step).
     """
-    m, bsz, dim = u.shape
-    block_d, u, v, g_new, s, hg_old = _pad_features(
-        block_d, dim, u, v, g_new, s, hg_old)
-    dim_p = u.shape[-1]
-    nd = dim_p // block_d
-    alpha_arr = jnp.broadcast_to(jnp.asarray(alpha, jnp.float32), (1,))
-
-    tile = pl.BlockSpec((m, 1, block_d), lambda bb, ph, j, sl: (0, bb, j))
-    row = pl.BlockSpec((1, 1, block_d), lambda bb, ph, j, sl: (sl[bb], bb, j))
-    vec = pl.BlockSpec((1, block_d), lambda bb, ph, j, sl: (bb, j))
-    mask_spec = pl.BlockSpec((m, 1), lambda bb, ph, j, sl: (0, bb))
-    one = pl.BlockSpec((1,), lambda bb, ph, j, sl: (0,))
-    per_b = pl.BlockSpec((1,), lambda bb, ph, j, sl: (bb,))
-    coeff_spec = pl.BlockSpec((1, 2, m), lambda bb, ph, j, sl: (bb, 0, 0))
-    den_spec = pl.BlockSpec((1, 1), lambda bb, ph, j, sl: (bb, 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(bsz, 2, nd),
-        in_specs=[tile, tile, vec, vec, vec, mask_spec, one, per_b],
-        out_specs=[row, row, vec, vec, vec, vec, coeff_spec, den_spec],
-    )
-    outs = pl.pallas_call(
-        _make_broyden_step_kernel(eps, nd),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(u.shape, u.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-            jax.ShapeDtypeStruct((bsz, dim_p), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, dim_p), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, dim_p), u.dtype),
-            jax.ShapeDtypeStruct((bsz, dim_p), v.dtype),
-            jax.ShapeDtypeStruct((bsz, 2, m), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, 1), jnp.float32),
-        ],
-        # aliasing indices count the scalar-prefetch operand: slot=0, u=1, v=2
-        input_output_aliases={1: 0, 2: 1},
-        interpret=interpret,
-    )(slot, u, v, g_new.astype(jnp.float32), s.astype(jnp.float32),
-      hg_old.astype(jnp.float32), mask, alpha_arr, active.astype(jnp.float32))
-    new_u, new_v, hg_new, b, ev_u, ev_v, _coeff, den = outs
-    if dim_p != dim:
-        new_u, new_v = new_u[..., :dim], new_v[..., :dim]
-        hg_new, b = hg_new[..., :dim], b[..., :dim]
-        ev_u, ev_v = ev_u[..., :dim], ev_v[..., :dim]
-    return new_u, new_v, hg_new, b, den[:, 0], ev_u, ev_v
+    xs = jnp.stack([g_new.astype(jnp.float32), s.astype(jnp.float32)])
+    out, ev_u, ev_v = _qn_call(u, v, xs, alpha, mask, (False, True),
+                               slot=slot, block_d=block_d,
+                               interpret=interpret)
+    return out[0], out[1], ev_u, ev_v

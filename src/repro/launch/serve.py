@@ -1,14 +1,14 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Spins up the fixed-slot continuous-batching loop (runtime/serving.py) on a
-reduced config and drains a synthetic request stream — the CPU-runnable
-counterpart of the decode_32k / long_500k dry-run cells.
+Spins up the fixed-slot continuous-batching loop (runtime/serving.py) at the
+architecture's published width (``--smoke``: the reduced same-family config
+a CPU runs) and drains a synthetic request stream.  ``main(argv)`` returns
+the drained requests to in-process callers.
 
 ``--mesh DxM`` runs the loop sharded (decode rules: batch over "data",
-sequence-sharded KV over "model") on a forced multi-device host platform —
-the CPU rehearsal of the sharded batched serving path.  Set it together
-with ``--force-devices N`` (which must win the race with jax backend
-initialization, so it is applied before any device query).
+sequence-sharded KV over "model") over the devices present.  On a CPU set it
+together with ``--force-devices N`` (which must win the race with jax
+backend initialization, so it is applied before any device query).
 """
 
 from __future__ import annotations
@@ -18,9 +18,11 @@ import os
 import time
 
 
-def main() -> None:
+def main(argv: list[str] | None = None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="minicpm-2b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced same-family config (CPU-runnable)")
     ap.add_argument("--deq", action="store_true")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
@@ -90,7 +92,7 @@ def main() -> None:
     ap.add_argument("--trace-out", default="",
                     help="write a Chrome-trace JSON of the drain here "
                          "(enables span tracing)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.force_devices:
         flags = os.environ.get("XLA_FLAGS", "")
@@ -101,14 +103,16 @@ def main() -> None:
     import jax
     import numpy as np
 
-    from repro.configs.registry import ARCHS, smoke_config
-    from repro.launch.mesh import make_test_mesh
+    from repro.configs.registry import ARCHS, get_config, smoke_config
+    from repro.launch.compile_cache import use_persistent_cache
+    from repro.launch.mesh import make_device_mesh, parse_mesh
     from repro.models import lm
     from repro.obs import metrics as obs_metrics
     from repro.obs import tracing as obs_tracing
     from repro.parallel.sharding import DECODE_RULES, ShardCtx
     from repro.runtime.serving import Request, ServeLoop
 
+    use_persistent_cache()
     # trace-time gates: enable before the loop's first jit trace
     if args.metrics_out or args.metrics_prom_out:
         obs_metrics.set_enabled(True)
@@ -119,7 +123,8 @@ def main() -> None:
 
     if args.arch not in ARCHS:
         raise SystemExit(f"unknown arch {args.arch!r}; have {sorted(ARCHS)}")
-    cfg = smoke_config(args.arch, deq=args.deq)
+    cfg = smoke_config(args.arch, deq=args.deq) if args.smoke \
+        else get_config(args.arch, deq=args.deq)
     if args.qn_dtype or args.no_guard:
         import dataclasses
         deq = cfg.deq
@@ -131,12 +136,7 @@ def main() -> None:
     if cfg.family == "audio":
         raise SystemExit("encoder-only arch: no autoregressive serving")
     if args.mesh:
-        d, m = (int(v) for v in args.mesh.lower().split("x"))
-        if len(jax.devices()) < d * m:
-            raise SystemExit(
-                f"mesh {d}x{m} needs {d*m} devices, have "
-                f"{len(jax.devices())} (use --force-devices)")
-        mesh = make_test_mesh((d, m), ("data", "model"))
+        mesh = make_device_mesh(parse_mesh(args.mesh), ("data", "model"))
         ctx = ShardCtx.for_mesh(mesh, DECODE_RULES)
     else:
         ctx = ShardCtx.for_mesh(None)
@@ -200,6 +200,7 @@ def main() -> None:
     if args.trace_out:
         obs_tracing.write(args.trace_out)
         print(f"chrome trace -> {args.trace_out}")
+    return reqs
 
 
 if __name__ == "__main__":

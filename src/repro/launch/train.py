@@ -1,9 +1,12 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> [...]``.
 
 Runs the Trainer (checkpoint/restart, preemption handling, straggler
-watchdog) on any assigned architecture — full config, a reduced ``--smoke``
-config, or the DEQ/SHINE form of it (``--deq``). On this CPU container use
-``--smoke``; the full configs are the multi-pod dry-run's job.
+watchdog) on any assigned architecture — the published config, a reduced
+``--smoke`` config (runs on a CPU), or the DEQ/SHINE form of either
+(``--deq``).  ``--mesh DxM`` trains on a (data=D, model=M) mesh over the
+devices present (a host's chips, or forced CPU devices in a rehearsal).
+``main(argv)`` also serves in-process callers: it returns the final state
+and the logged metrics of every logged step.
 """
 
 from __future__ import annotations
@@ -18,14 +21,16 @@ from repro.configs.registry import ARCHS, get_config, smoke_config
 from repro.configs.shapes import SHAPES, make_ctx
 from repro.data.pipeline import make_lm_batch_iterator
 from repro.implicit import ESTIMATORS, SOLVERS
-from repro.launch.mesh import make_production_mesh
+from repro.launch.compile_cache import use_persistent_cache
+from repro.launch.mesh import make_device_mesh, parse_mesh
+from repro.models import lm
 from repro.obs import metrics as obs_metrics
 from repro.obs import tracing as obs_tracing
 from repro.parallel.sharding import ShardCtx
 from repro.runtime.trainer import Trainer
 
 
-def main() -> None:
+def main(argv: list[str] | None = None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), default="minicpm-2b")
     ap.add_argument("--smoke", action="store_true",
@@ -43,7 +48,11 @@ def main() -> None:
     ap.add_argument("--grad-accum", type=int, default=1)
     ap.add_argument("--checkpoint-dir", default="")
     ap.add_argument("--checkpoint-every", type=int, default=0)
-    ap.add_argument("--mesh", choices=("none", "single", "multi"), default="none")
+    ap.add_argument("--mesh", default=None, metavar="DxM",
+                    help="train on a (data=D, model=M) mesh over the "
+                         "devices present")
+    ap.add_argument("--log-every", type=int, default=10,
+                    help="fetch and print metrics every this many steps")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--metrics-out", default="",
                     help="write a metrics-registry JSON snapshot here after "
@@ -69,7 +78,8 @@ def main() -> None:
     ap.add_argument("--skip-budget", type=int, default=None,
                     help="consecutive non-finite-update skips tolerated "
                          "before rolling back to the last checkpoint")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    use_persistent_cache()
 
     # observability switches are trace-time gates: enable BEFORE the first
     # jit trace so the compiled programs carry the instrumentation
@@ -94,11 +104,11 @@ def main() -> None:
             deq = dataclasses.replace(deq, guard=False)
         cfg = dataclasses.replace(cfg, deq=deq)
 
-    if args.mesh == "none":
-        ctx = ShardCtx.for_mesh(None)
-    else:
-        mesh = make_production_mesh(multi_pod=(args.mesh == "multi"))
+    if args.mesh:
+        mesh = make_device_mesh(parse_mesh(args.mesh), ("data", "model"))
         ctx = make_ctx(cfg, mesh, SHAPES["train_4k"])
+    else:
+        ctx = ShardCtx.for_mesh(None)
 
     tcfg = TrainConfig(
         steps=args.steps, global_batch=args.batch, seq_len=args.seq,
@@ -113,12 +123,14 @@ def main() -> None:
            if args.skip_budget is not None else {}),
     )
 
-    print(f"arch={cfg.name} params={cfg.num_params()/1e6:.1f}M "
+    print(f"arch={cfg.name} params={lm.param_count(cfg)/1e6:.1f}M "
           f"deq={cfg.deq.enabled} devices={jax.device_count()}")
     trainer = Trainer(cfg, tcfg, ctx)
     batches = make_lm_batch_iterator(cfg, ctx, args.batch, args.seq,
                                      seed=args.seed)
-    state = trainer.run(batches, steps=args.steps)
+    history: list[tuple[int, dict]] = []
+    state = trainer.run(batches, steps=args.steps, log_every=args.log_every,
+                        on_metrics=lambda i, m: history.append((i, m)))
     batches.close()
     print(f"finished at step {int(state.step)}")
 
@@ -131,6 +143,7 @@ def main() -> None:
     if args.trace_out:
         obs_tracing.write(args.trace_out)
         print(f"chrome trace -> {args.trace_out}")
+    return state, history
 
 
 if __name__ == "__main__":

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import inspect
 from typing import Any, NamedTuple
 
 import jax
@@ -31,6 +32,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.lowrank import LowRank
+from repro.kernels import ops as kernel_ops
 from repro.implicit import (
     ImplicitConfig,
     SolveCarry,
@@ -510,6 +512,19 @@ def _apply_deq(params, x_emb, cfg, ctx, positions, caches, cache_index, train,
 # ---------------------------------------------------------------------------
 
 
+def _kernels_on_mesh(fn):
+    """Trace ``fn``'s Pallas kernels per device shard of its ``ctx``
+    argument's mesh (kernels.ops.sharded_kernels)."""
+    sig = inspect.signature(fn)
+
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        ctx = sig.bind(*args, **kwargs).arguments["ctx"]
+        with kernel_ops.sharded_kernels(ctx):
+            return fn(*args, **kwargs)
+    return wrapped
+
+
 def _input_embedding(params, batch: dict, cfg: ModelConfig, ctx: ShardCtx):
     """Token/frontend embedding. Returns (x (B,S,d), positions (B,S))."""
     if cfg.family == "audio":
@@ -531,6 +546,7 @@ def _input_embedding(params, batch: dict, cfg: ModelConfig, ctx: ShardCtx):
     return x, pos
 
 
+@_kernels_on_mesh
 def forward(params, batch: dict, cfg: ModelConfig, ctx: ShardCtx,
             train: bool = True, carry: SolveCarry | None = None):
     """Full-sequence forward. Returns (logits, aux).
@@ -697,6 +713,7 @@ def prefix_gather_carry(cfg: ModelConfig, batch: int, seq: int,
     return carry, prefix_len
 
 
+@_kernels_on_mesh
 def prefill(params, batch: dict, cfg: ModelConfig, ctx: ShardCtx,
             max_len: int, carry: SolveCarry | None = None,
             prefix_carry: SolveCarry | None = None,
@@ -762,6 +779,7 @@ def prefill(params, batch: dict, cfg: ModelConfig, ctx: ShardCtx,
     return out
 
 
+@_kernels_on_mesh
 def decode_step(params, caches, tokens: Array, cache_index: Array,
                 cfg: ModelConfig, ctx: ShardCtx, active: Array | None = None,
                 carry: SolveCarry | None = None, return_steps: bool = False,

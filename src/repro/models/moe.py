@@ -24,7 +24,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import mlp, mlp_decl
-from repro.parallel.sharding import ParamDecl, ShardCtx, shard_map_compat
+from repro.parallel.sharding import ParamDecl, ShardCtx
 
 Array = jax.Array
 
@@ -138,10 +138,11 @@ def moe_block(params: dict, x: Array, cfg: ModelConfig, ctx: ShardCtx
             "wo": ctx.rules.spec(("expert_act", None, None)),
         }
         routed = {k: params[k] for k in ("router", "wi_g", "wi_u", "wo")}
-        out, aux, zloss = shard_map_compat(
-            shard_fn, mesh,
+        out, aux, zloss = jax.shard_map(
+            shard_fn, mesh=mesh,
             in_specs=(batch_spec, pspecs),
             out_specs=(batch_spec, P(), P()),
+            check_vma=False,
         )(xf, routed)
 
     out = out.reshape(b, s, d)

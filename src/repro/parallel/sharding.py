@@ -326,18 +326,3 @@ class ShardCtx:
 
 
 NULL_CTX = ShardCtx(mesh=None, rules=ShardingRules({k: None for k in _base_table()}))
-
-
-def shard_map_compat(f, mesh, *, in_specs, out_specs):
-    """Per-device mapping across jax versions: ``jax.shard_map`` (with its
-    ``check_vma`` flag) only exists from 0.6; older versions expose the same
-    semantics as ``jax.experimental.shard_map.shard_map`` with ``check_rep``.
-    Replication checking is off in both spellings — mapped bodies issue
-    their own psum/pmean collectives."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)

@@ -155,15 +155,18 @@ class Trainer:
                         jax.block_until_ready(metrics)
                 n_since += 1
                 if (i + 1) % log_every == 0 or i + 1 == steps:
-                    # the interval's ONE host sync: a single device_get of
-                    # the metrics tree — the steps in between dispatched
-                    # back-to-back with no blocking fetch on the hot path
+                    # the interval's ONE host sync: wait for the step's
+                    # outputs, then a single device_get of the metrics tree
+                    # — the steps in between dispatched back-to-back with
+                    # no blocking fetch on the hot path
+                    jax.block_until_ready((state, metrics))
+                    now = time.perf_counter()
                     metrics = {k: float(v)
                                for k, v in jax.device_get(metrics).items()}
-                    now = time.perf_counter()
                     # this sync point drains every step since the last one,
                     # so the honest per-step time is the interval average
                     dt = (now - t_sync) / max(n_since, 1)
+                    metrics["step_time_s"] = dt
                     t_sync, n_since = now, 0
                     self.watchdog.record(host, dt)
                     self.watchdog.publish_metrics()
@@ -173,12 +176,11 @@ class Trainer:
                         state = self._rollback(i + 1)
                     if on_metrics:
                         on_metrics(i + 1, metrics)
-                    else:
-                        print(
-                            f"step {i+1:5d} loss={metrics['loss']:.4f} "
-                            f"gnorm={metrics['grad_norm']:.3f} "
-                            f"lr={metrics['lr']:.2e} {dt*1e3:.0f}ms"
-                        )
+                    print(
+                        f"step {i+1:5d} loss={metrics['loss']:.4f} "
+                        f"gnorm={metrics['grad_norm']:.3f} "
+                        f"lr={metrics['lr']:.2e} {dt*1e3:.0f}ms"
+                    )
                 if self.ckpt and self.tcfg.checkpoint_every and (
                     (i + 1) % self.tcfg.checkpoint_every == 0
                 ):

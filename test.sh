@@ -63,13 +63,13 @@ run_obs() {
   python -m repro.launch.train --smoke --deq --steps 2 --batch 2 --seq 16 \
     --metrics-out results/obs/train_metrics.json \
     --trace-out results/obs/train_trace.json
-  python -m repro.launch.serve --deq --requests 6 --slots 2 \
+  python -m repro.launch.serve --smoke --deq --requests 6 --slots 2 \
     --max-new-tokens 4 --carry-max-age 3 \
     --metrics-out results/obs/serve_metrics.json \
     --trace-out results/obs/serve_trace.json
   # prefix-cache drain: overlapping prompts (6 shared tokens) through the
   # cross-request prefix carry cache — the snapshot must record hits
-  python -m repro.launch.serve --deq --requests 6 --slots 2 \
+  python -m repro.launch.serve --smoke --deq --requests 6 --slots 2 \
     --max-new-tokens 4 --prefix-cache --prefix-cache-slots 8 \
     --shared-prefix 6 \
     --metrics-out results/obs/serve_prefix_metrics.json \
@@ -77,7 +77,7 @@ run_obs() {
   # async-pipeline drain: device-resident caches + wave overlap + reorder;
   # the validator asserts host_syncs_total == 0 (the steady state never
   # blocks on unready device data) and a parseable Prometheus exposition
-  python -m repro.launch.serve --deq --requests 8 --slots 2 \
+  python -m repro.launch.serve --smoke --deq --requests 8 --slots 2 \
     --max-new-tokens 4 --pipeline async --prefix-cache \
     --prefix-cache-slots 8 --shared-prefix 6 --reorder \
     --metrics-out results/obs/serve_async_metrics.json \

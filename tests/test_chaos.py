@@ -371,7 +371,9 @@ def test_sigterm_preemption_writes_final_checkpoint(tmp_path):
     paths = [os.path.join(repo, "src")]
     if os.environ.get("PYTHONPATH"):
         paths.append(os.environ["PYTHONPATH"])
+    # the launcher would keep a persistent compile cache: not in tests
     env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_ENABLE_COMPILATION_CACHE="false",
                PYTHONPATH=os.pathsep.join(paths))
     proc = subprocess.Popen(
         [sys.executable, "-m", "repro.launch.train", "--smoke", "--deq",

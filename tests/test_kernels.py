@@ -13,7 +13,6 @@ from repro.kernels.flash_attention import (
     flash_attention_pallas,
 )
 from repro.kernels.flash_xla import flash_attention_xla
-from repro.kernels.qn_apply import qn_apply_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 
 KEY = jax.random.PRNGKey(0)
@@ -32,7 +31,8 @@ def _tol(dtype):
 
 
 @pytest.mark.parametrize("m,bsz,d", [(1, 1, 8), (4, 2, 64), (8, 3, 100),
-                                     (16, 2, 512), (30, 1, 1000)])
+                                     (16, 2, 512), (30, 1, 1000),
+                                     (8, 12, 300), (8, 16, 256)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_qn_apply_pallas_vs_oracle(m, bsz, d, dtype):
     ks = jax.random.split(jax.random.fold_in(KEY, m * 1000 + d), 4)
@@ -143,7 +143,8 @@ def test_qn_stream_bytes_accounting():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("m,bsz,d", [(2, 1, 8), (6, 3, 100), (16, 2, 512)])
+@pytest.mark.parametrize("m,bsz,d", [(2, 1, 8), (6, 3, 100), (16, 2, 512),
+                                     (8, 13, 200), (8, 16, 640)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_lowrank_append_pallas_vs_oracle(m, bsz, d, dtype):
     ks = jax.random.split(jax.random.fold_in(KEY, m * 31 + d), 7)
@@ -184,7 +185,8 @@ def _broyden_step_inputs(m, bsz, d, dtype, key):
     return u, v, g, s, hg, mask, slot
 
 
-@pytest.mark.parametrize("m,bsz,d", [(1, 1, 8), (5, 2, 777), (16, 3, 512)])
+@pytest.mark.parametrize("m,bsz,d", [(1, 1, 8), (5, 2, 777), (16, 3, 512),
+                                     (8, 12, 300), (8, 16, 384)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_broyden_step_pallas_vs_oracle(m, bsz, d, dtype):
     """Fused kernel vs the ref oracle: ragged ring counts, m % 8 != 0 (the
@@ -311,7 +313,29 @@ def test_flash_attention_pallas_vs_oracle(b, s, h, kv, hd, causal):
                                rtol=2e-3, atol=2e-3)
 
 
-@pytest.mark.parametrize("b,t,h,kv,hd", [(2, 256, 4, 4, 64), (1, 512, 8, 2, 64)])
+@pytest.mark.parametrize("b,s,h,kv,hd,causal", [
+    (2, 100, 4, 4, 64, True),
+    (3, 300, 4, 2, 64, True),
+    (1, 77, 8, 2, 64, False),
+])
+def test_flash_attention_ragged_length_kv_masking(b, s, h, kv, hd, causal):
+    """Prompt lengths off the 128-row block are padded and the valid kv
+    length masks both the padding and each row's own tail."""
+    ks = jax.random.split(jax.random.fold_in(KEY, s + 7 * h), 4)
+    q = jax.random.normal(ks[0], (b, s, h, hd), jnp.float32)
+    k = jax.random.normal(ks[1], (b, s, kv, hd), jnp.float32)
+    v = jax.random.normal(ks[2], (b, s, kv, hd), jnp.float32)
+    kv_len = jax.random.randint(ks[3], (b,), s // 2, s + 1)
+    want = ref.attention_ref(q, k, v, causal=causal, kv_length=kv_len)
+    got = ops.attention(q, k, v, causal=causal, kv_length=kv_len,
+                        impl="pallas_interpret")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("b,t,h,kv,hd", [(2, 256, 4, 4, 64), (1, 512, 8, 2, 64),
+                                         (3, 512, 36, 36, 64),
+                                         (2, 200, 8, 2, 64)])
 def test_decode_attention_pallas_vs_oracle(b, t, h, kv, hd):
     ks = jax.random.split(jax.random.fold_in(KEY, t + h), 4)
     q = jax.random.normal(ks[0], (b, h, hd), jnp.float32)

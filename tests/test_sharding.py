@@ -75,7 +75,7 @@ from repro.configs.base import TrainConfig
 from repro.configs.registry import smoke_config
 from repro.configs.shapes import SHAPES, make_ctx
 from repro.launch import steps
-from repro.launch.mesh import make_test_mesh
+from repro.launch.mesh import make_device_mesh
 from repro.models import lm
 """
 
@@ -109,7 +109,7 @@ def test_sharded_train_step_matches_single_device():
     s0, m0 = jax.jit(step0)(state0, batch)
 
     # 2x2 mesh
-    mesh = make_test_mesh((2, 2), ("data", "model"))
+    mesh = make_device_mesh((2, 2), ("data", "model"))
     ctx = make_ctx(cfg, mesh, SHAPES["train_4k"])
     stepf = steps.build_train_step(cfg, tcfg, ctx)
     with mesh:
@@ -138,7 +138,7 @@ def test_sharded_decode_matches_single_device():
     logits0, caches0, lens = lm.prefill(params, {"tokens": toks[:, :8]}, cfg, ctx0, 16)
     dec0, _ = lm.decode_step(params, caches0, toks[:, 8], lens, cfg, ctx0)
 
-    mesh = make_test_mesh((2, 4), ("data", "model"))
+    mesh = make_device_mesh((2, 4), ("data", "model"))
     ctx = make_ctx(cfg, mesh, SHAPES["decode_32k"])
     with mesh:
         logits1, caches1, lens1 = jax.jit(
@@ -167,7 +167,7 @@ def test_seq_parallel_matches_baseline():
     ctx0 = ShardCtx.for_mesh(None)
     out0, _ = lm.forward(params, batch, cfg, ctx0, train=False)
 
-    mesh = make_test_mesh((2, 4), ("data", "model"))
+    mesh = make_device_mesh((2, 4), ("data", "model"))
     cfg_sp = dataclasses.replace(cfg, seq_parallel=True)
     ctx = make_ctx(cfg_sp, mesh, SHAPES["train_4k"])
     with mesh:
@@ -198,7 +198,7 @@ def test_sharded_deq_train_step_matches_single_device():
     state0 = steps.init_train_state(cfg, tcfg, ctx0)
     s0, m0 = jax.jit(step0)(state0, batch)
 
-    mesh = make_test_mesh((2, 2), ("data", "model"))
+    mesh = make_device_mesh((2, 2), ("data", "model"))
     ctx = make_ctx(cfg, mesh, SHAPES["train_4k"])
     stepf = steps.build_train_step(cfg, tcfg, ctx)
     with mesh:
@@ -216,6 +216,43 @@ def test_sharded_deq_train_step_matches_single_device():
 
 
 @pytest.mark.slow
+def test_sharded_deq_train_step_kernel_route_matches_single_device():
+    """The TPU path of a sharded DEQ train step: every Pallas kernel (qN
+    ring, attention, rmsnorm) runs under shard_map on its device's rows —
+    GSPMD cannot partition a pallas_call.  Interpret mode on a (2,2) CPU
+    mesh must match the single-device step, with the ring batch-sharded."""
+    _run_sub("""
+    from repro.kernels import ops
+    from repro.parallel.sharding import ShardCtx
+    ops.force_impl("pallas_interpret")
+    cfg = smoke_config("minicpm-2b", deq=True)
+    cfg = dataclasses.replace(cfg, num_layers=2, vocab_size=256)
+    tcfg = TrainConfig(steps=1, global_batch=4, seq_len=16, lr=1e-3, zero1=True)
+    toks = np.random.default_rng(0).integers(0, 256, size=(4, 17))
+    batch = {"tokens": jnp.asarray(toks[:, :-1]), "targets": jnp.asarray(toks[:, 1:])}
+
+    ctx0 = ShardCtx.for_mesh(None)
+    s0, m0 = jax.jit(steps.build_train_step(cfg, tcfg, ctx0))(
+        steps.init_train_state(cfg, tcfg, ctx0), batch)
+
+    mesh = make_device_mesh((2, 2), ("data", "model"))
+    ctx = make_ctx(cfg, mesh, SHAPES["train_4k"])
+    shard = steps.state_shardings(cfg, tcfg, ctx)
+    stepf = jax.jit(steps.build_train_step(cfg, tcfg, ctx),
+                    in_shardings=(shard, None), out_shardings=(shard, None))
+    s1, m1 = stepf(steps.init_train_state(cfg, tcfg, ctx), batch)
+
+    np.testing.assert_allclose(float(m0["loss"]), float(m1["loss"]), rtol=2e-2)
+    np.testing.assert_allclose(float(m0["grad_norm"]), float(m1["grad_norm"]),
+                               rtol=2e-2)
+    u = s1.carry.lowrank.u
+    assert {sh.data.shape[1] for sh in u.addressable_shards} == {2}, \
+        [sh.data.shape for sh in u.addressable_shards]
+    print("OK")
+    """)
+
+
+@pytest.mark.slow
 def test_sharded_batched_solve_qn_memory_layout():
     """The batched engine under a mesh: per-sample masking + early exit hold,
     padding slots return untouched, and the quasi-Newton (U, V) buffers are
@@ -226,7 +263,7 @@ def test_sharded_batched_solve_qn_memory_layout():
     from repro.implicit import ImplicitConfig, batched_solve
     from repro.parallel.sharding import ShardCtx, TRAIN_RULES
 
-    mesh = make_test_mesh((4, 2), ("data", "model"))
+    mesh = make_device_mesh((4, 2), ("data", "model"))
     ctx = ShardCtx.for_mesh(mesh, TRAIN_RULES)
     d = 16
     A = 0.5 * jax.random.normal(jax.random.PRNGKey(0), (d, d)) / np.sqrt(d)
@@ -280,7 +317,7 @@ def test_deq_carry_checkpoint_roundtrip_under_resharding():
     toks = np.random.default_rng(0).integers(0, 256, size=(8, 17))
     batch = {"tokens": jnp.asarray(toks[:, :-1]), "targets": jnp.asarray(toks[:, 1:])}
 
-    mesh = make_test_mesh((2, 2), ("data", "model"))
+    mesh = make_device_mesh((2, 2), ("data", "model"))
     ctx = make_ctx(cfg, mesh, SHAPES["train_4k"])
     stepf = steps.build_train_step(cfg, tcfg, ctx)
     with mesh:
@@ -292,7 +329,7 @@ def test_deq_carry_checkpoint_roundtrip_under_resharding():
     mgr = CheckpointManager(tmp, keep=1, async_save=False)
     mgr.save(1, state)
 
-    mesh2 = make_test_mesh((4, 2), ("data", "model"))
+    mesh2 = make_device_mesh((4, 2), ("data", "model"))
     ctx2 = make_ctx(cfg, mesh2, SHAPES["train_4k"])
     shard2 = steps.state_shardings(cfg, tcfg, ctx2)
     with mesh2:
@@ -320,14 +357,15 @@ def test_deq_carry_checkpoint_roundtrip_under_resharding():
 
 @pytest.mark.slow
 def test_qn_apply_multi_shard_map_parity():
-    """ROADMAP item: explicit shard_map wrapper for the batch-sharded
-    ``qn_apply_multi`` kernel path.  The wrapper pins per-shard tile sizes
-    (block_d) and must agree bit-for-tolerance with BOTH the jnp oracle and
-    the GSPMD route (plain op on batch-sharded operands), interpret mode."""
+    """The batch-sharded kernel route: inside ``ops.sharded_kernels`` the
+    ``qn_apply_multi`` kernel runs under shard_map on each device's batch
+    rows and must agree with BOTH the jnp oracle and the unmapped kernel on
+    batch-sharded operands, interpret mode."""
     _run_sub("""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.kernels import ops, ref
-    mesh = make_test_mesh((4, 2), ("data", "model"))
+    from repro.parallel.sharding import ShardCtx
+    mesh = make_device_mesh((4, 2), ("data", "model"))
     m, b, d, kk = 8, 8, 256, 2
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     u = jax.random.normal(ks[0], (m, b, d))
@@ -344,9 +382,10 @@ def test_qn_apply_multi_shard_map_parity():
         got_gspmd = jax.jit(lambda a, bb, c, dd: ops.qn_apply_multi(
             a, bb, c, jnp.float32(1.0), dd, tr, impl="pallas_interpret")
         )(us, vs, xss, ms)
-        got_sm = jax.jit(lambda a, bb, c, dd: ops.qn_apply_multi_sharded(
-            a, bb, c, jnp.float32(1.0), dd, tr, mesh=mesh,
-            impl="pallas_interpret", block_d=128))(us, vs, xss, ms)
+        with ops.sharded_kernels(ShardCtx.for_mesh(mesh)):
+            got_sm = jax.jit(lambda a, bb, c, dd: ops.qn_apply_multi(
+                a, bb, c, jnp.float32(1.0), dd, tr, impl="pallas_interpret",
+                block_d=128))(us, vs, xss, ms)
     # 1e-4: interpret-mode tile-order reductions differ from the oracle's
     np.testing.assert_allclose(np.asarray(got_sm), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
@@ -378,7 +417,7 @@ def test_moe_expert_parallel_matches_single_device():
     batch = {"tokens": toks}
     ctx0 = ShardCtx.for_mesh(None)
     out0, _ = lm.forward(params, batch, cfg, ctx0, train=False)
-    mesh = make_test_mesh((2, 4), ("data", "model"))
+    mesh = make_device_mesh((2, 4), ("data", "model"))
     ctx = make_ctx(cfg, mesh, SHAPES["train_4k"])
     with mesh:
         out1, _ = jax.jit(lambda p, b: lm.forward(p, b, cfg, ctx, train=False))(params, batch)

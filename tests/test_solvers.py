@@ -212,7 +212,7 @@ def test_lbfgs_two_loop_is_shine_inverse():
                 (jnp.linalg.norm(got) * jnp.linalg.norm(want)))
     # Seeds are pinned (PRNGKey 9/10), but the achieved alignment still
     # moves with jax version / CPU reduction order: observed cos = 0.94992
-    # on jax 0.4.37 CPU, right under the old 0.95 cut.  The probe direction
+    # on an older jax, right under the old 0.95 cut.  The probe direction
     # w is random, NOT confined to the explored secant subspace, so ~0.95
     # is the honest quality level — 0.90 keeps real regressions visible
     # (a broken two-loop scores < 0.5 here) with headroom against
