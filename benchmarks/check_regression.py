@@ -181,8 +181,9 @@ def measure_obs_overhead(reps: int = 5) -> dict:
     The work is pinned (tol=0 -> the forward always runs max_steps, the
     backward budget is fixed), so the only delta between the two modes is
     the instrumentation itself: the debug-callback bridge planted by
-    ``record_solve``/``record_backward`` and the ``phase_done`` trace
-    marks.  Fresh jit closures per mode — the gates are trace-time."""
+    ``record_solve``/``record_backward`` (the span tracer plants nothing in
+    a program: its phases are ``jax.named_scope`` names, metadata only).
+    Fresh jit closures per mode — the gate is trace-time."""
     import time
 
     import jax
@@ -192,10 +193,9 @@ def measure_obs_overhead(reps: int = 5) -> dict:
     from repro.implicit import (BackwardConfig, ForwardConfig, ImplicitConfig,
                                 implicit_fixed_point)
     from repro.obs import metrics as obs_metrics
-    from repro.obs import tracing as obs_tracing
 
     # The instrumentation cost is a FIXED per-solve-call amount (a handful
-    # of host callbacks: solve record, backward record, phase marks —
+    # of host callbacks: solve record, backward record —
     # ~3-4 ms of host Python on this class of machine), independent of the
     # solve size.  Size the probe like a real train step (~100 ms+), where
     # that fixed cost is the same <5% it is in production; a tiny probe
@@ -217,7 +217,6 @@ def measure_obs_overhead(reps: int = 5) -> dict:
         # the gates are trace-time: the enabled state at COMPILE decides
         # whether the program carries callbacks, regardless of later flips
         obs_metrics.set_enabled(enable)
-        obs_tracing.set_enabled(enable)
 
         def loss(params, xx):
             z, _ = implicit_fixed_point(f, params, xx, jnp.zeros_like(xx), cfg)
@@ -232,7 +231,7 @@ def measure_obs_overhead(reps: int = 5) -> dict:
         jax.block_until_ready(g(W, x))
         return (time.perf_counter() - t0) * 1e3
 
-    was_m, was_t = obs_metrics.enabled(), obs_tracing.enabled()
+    was_m = obs_metrics.enabled()
     try:
         g_off = compiled(False)
         g_on = compiled(True)
@@ -250,7 +249,6 @@ def measure_obs_overhead(reps: int = 5) -> dict:
             deltas.append(on - off)
     finally:
         obs_metrics.set_enabled(was_m)
-        obs_tracing.set_enabled(was_t)
     base = min(offs)
     return {"baseline_ms": base,
             "instrumented_ms": base + max(min(deltas), 0.0)}
@@ -286,7 +284,6 @@ def measure_guard_overhead(reps: int = 5) -> dict:
     from repro.implicit import (BackwardConfig, ForwardConfig, ImplicitConfig,
                                 implicit_fixed_point)
     from repro.obs import metrics as obs_metrics
-    from repro.obs import tracing as obs_tracing
 
     B, D = 8, 2048
     rng = np.random.default_rng(0)
@@ -317,9 +314,8 @@ def measure_guard_overhead(reps: int = 5) -> dict:
         return (time.perf_counter() - t0) * 1e3
 
     # isolate the guard delta: the obs bridge must not ride either arm
-    was_m, was_t = obs_metrics.enabled(), obs_tracing.enabled()
+    was_m = obs_metrics.enabled()
     obs_metrics.set_enabled(False)
-    obs_tracing.set_enabled(False)
     try:
         g_off = compiled(False)
         g_on = compiled(True)
@@ -333,7 +329,6 @@ def measure_guard_overhead(reps: int = 5) -> dict:
             deltas.append(on - off)
     finally:
         obs_metrics.set_enabled(was_m)
-        obs_tracing.set_enabled(was_t)
     base = min(offs)
     return {"baseline_ms": base,
             "guarded_ms": base + max(min(deltas), 0.0)}

@@ -578,26 +578,28 @@ def broyden_solve(
 
         s = (z_new - z).astype(jnp.float32)
         g_new32 = gz_new.astype(jnp.float32)
-        wrapped = H.count >= H.memory                 # slot being overwritten
-        # THE per-step U/V stream: the fused broyden_step kernel computes
-        # H @ g(z_new), H^T @ s, the denominator s^T H y, AND the guarded
-        # ring append in a single launch — one pass, write included.
-        H, Hg_new, b, den, upd, ev_u, ev_v = H.broyden_step(
-            g_new32, s, Hg, active, cfg.eps)
-        Hy = Hg_new - Hg                              # H @ (g_new - g_old)
-        denom = jnp.where(jnp.abs(den) > cfg.eps, den, 1.0)
+        with jax.named_scope("qn_update"):
+            wrapped = H.count >= H.memory             # slot being overwritten
+            # THE per-step U/V stream: the fused broyden_step kernel computes
+            # H @ g(z_new), H^T @ s, the denominator s^T H y, AND the guarded
+            # ring append in a single launch — one pass, write included.
+            H, Hg_new, b, den, upd, ev_u, ev_v = H.broyden_step(
+                g_new32, s, Hg, active, cfg.eps)
+            Hy = Hg_new - Hg                          # H @ (g_new - g_old)
+            denom = jnp.where(jnp.abs(den) > cfg.eps, den, 1.0)
 
-        # Advance the carried product to H_{n+1} @ g_new: add the appended
-        # pair's contribution, remove the evicted pair's (storage precision,
-        # so the carry tracks what matvec over the new chain would compute).
-        a_st = ((s - Hy) / _expand(denom, s)).astype(H.u.dtype) \
-            .astype(jnp.float32)
-        b_st = b.astype(H.v.dtype).astype(jnp.float32)
-        gain = a_st * _expand(bdot(b_st, g_new32), s)
-        loss = ev_u.astype(jnp.float32) * _expand(
-            bdot(ev_v.astype(jnp.float32), g_new32)
-            * wrapped.astype(jnp.float32), s)
-        Hg = Hg_new + _expand(upd.astype(jnp.float32), s) * (gain - loss)
+            # Advance the carried product to H_{n+1} @ g_new: add the
+            # appended pair's contribution, remove the evicted pair's
+            # (storage precision, so the carry tracks what matvec over the
+            # new chain would compute).
+            a_st = ((s - Hy) / _expand(denom, s)).astype(H.u.dtype) \
+                .astype(jnp.float32)
+            b_st = b.astype(H.v.dtype).astype(jnp.float32)
+            gain = a_st * _expand(bdot(b_st, g_new32), s)
+            loss = ev_u.astype(jnp.float32) * _expand(
+                bdot(ev_v.astype(jnp.float32), g_new32)
+                * wrapped.astype(jnp.float32), s)
+            Hg = Hg_new + _expand(upd.astype(jnp.float32), s) * (gain - loss)
 
         res = bnorm(gz_new)
         if cfg.guard:
@@ -953,19 +955,20 @@ def adjoint_broyden_solve(
         # sigma^T J at z_new via VJP; sigma^T B via the B-chain (rmatvec).
         _, vjp = jax.vjp(g, z_new)
         sJT = vjp(sigma.astype(z_new.dtype))[0].astype(jnp.float32)
-        sB = B.rmatvec(sigma)
-        ss = bdot(sigma, sigma)
-        safe = ss > cfg.eps
-        w_row = (sJT - sB) / _expand(jnp.where(safe, ss, 1.0), sJT)
-        # H update: H <- H - (H sigma)(w^T H) / (1 + w^T H sigma).
-        # H sigma and w^T H batch through one fused U/V stream.
-        Hs, wH = H.matvec_multi((sigma, w_row), (False, True))
-        den = 1.0 + bdot(w_row, Hs)
-        safe = safe & (jnp.abs(den) > cfg.eps)
-        a = -Hs / _expand(jnp.where(safe, den, 1.0), Hs)
-        B = B.append(sigma, w_row, active & safe)
-        H = H.append(a, wH, active & safe)
-        return B, H
+        with jax.named_scope("qn_update"):
+            sB = B.rmatvec(sigma)
+            ss = bdot(sigma, sigma)
+            safe = ss > cfg.eps
+            w_row = (sJT - sB) / _expand(jnp.where(safe, ss, 1.0), sJT)
+            # H update: H <- H - (H sigma)(w^T H) / (1 + w^T H sigma).
+            # H sigma and w^T H batch through one fused U/V stream.
+            Hs, wH = H.matvec_multi((sigma, w_row), (False, True))
+            den = 1.0 + bdot(w_row, Hs)
+            safe = safe & (jnp.abs(den) > cfg.eps)
+            a = -Hs / _expand(jnp.where(safe, den, 1.0), Hs)
+            B = B.append(sigma, w_row, active & safe)
+            H = H.append(a, wH, active & safe)
+            return B, H
 
     def cond(state):
         k, conv = state[0], state[5]

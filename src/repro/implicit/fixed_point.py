@@ -33,7 +33,6 @@ from repro.implicit.estimators import estimate_cotangent
 from repro.implicit.pytree import ravel_state
 from repro.implicit.registry import SOLVERS
 from repro.obs import metrics as obs_metrics
-from repro.obs import tracing as obs_tracing
 from repro.obs.tape import SolveTape
 
 # populate the registry with the built-in solvers on import
@@ -105,7 +104,7 @@ def prepare_flat_problem(f, z0, ctx, state_axes):
 def _solve_forward(f_z, z0, cfg: ImplicitConfig, outer_grad=None,
                    sharding=None, freeze_mask=None, carry=None):
     solver = SOLVERS.get(cfg.forward.solver)
-    with kernel_scope(sharding):
+    with kernel_scope(sharding), jax.named_scope("deq_solve"):
         return _builtin_solvers.call_solver(
             solver, f_z, z0, cfg.solver_cfg(), outer_grad=outer_grad,
             sharding=sharding, freeze_mask=freeze_mask, carry=carry)
@@ -150,7 +149,6 @@ def _implicit(f, cfg: ImplicitConfig, outer_grad, sharding, params, x, z0,
     stats = ImplicitStats(res.residual, res.n_steps, res.converged, res.trace,
                           res.tape, res.status)
     obs_metrics.record_solve("forward", res, carry=carry)
-    obs_tracing.phase_done("forward_solve", res.n_steps)
     return res.z, stats, res.carry
 
 
@@ -166,7 +164,6 @@ def _implicit_fwd(f, cfg: ImplicitConfig, outer_grad, sharding, params, x, z0,
     stats = ImplicitStats(res.residual, res.n_steps, res.converged, res.trace,
                           res.tape, res.status)
     obs_metrics.record_solve("forward", res, carry=carry)
-    obs_tracing.phase_done("forward_solve", res.n_steps)
     return (res.z, stats, res.carry), (params, x, res.z, res.lowrank,
                                        res.status, _shape_structs(carry))
 
@@ -175,7 +172,7 @@ def _implicit_bwd(f, cfg: ImplicitConfig, outer_grad, sharding, saved,
                   cotangents):
     # traced after the forward's scopes have closed: re-open the kernel
     # scope for the VJP of f and the estimator's solves
-    with kernel_scope(sharding):
+    with kernel_scope(sharding), jax.named_scope("implicit_backward"):
         return _implicit_bwd_body(f, cfg, sharding, saved, cotangents)
 
 
@@ -190,7 +187,6 @@ def _implicit_bwd_body(f, cfg: ImplicitConfig, sharding, saved, cotangents):
     adj = estimate_cotangent(cfg, vjp_z, w, H, sharding=sharding,
                              forward_status=status)
     obs_metrics.record_backward(cfg.backward.estimator, adj)
-    obs_tracing.phase_done("implicit_backward", adj.n_steps)
     # Per-sample containment: a non-finite cotangent row (poisoned chain,
     # upstream NaN loss, faulted solve) skips its gradient contribution
     # instead of NaN-poisoning the whole batch's parameter gradient.

@@ -28,7 +28,6 @@ from repro.core.lowrank import LowRank
 from repro.core.solvers import SolveCarry, carry_state_only
 from repro.models import lm
 from repro.obs import metrics as obs_metrics
-from repro.obs import tracing as obs_tracing
 from repro.optim.optimizers import (
     OptState,
     adamw_init,
@@ -301,10 +300,6 @@ def build_train_step(
             metrics["consec_skips"] = new_state.skips.astype(jnp.float32)
             obs_metrics.emit_scalar("train_update_skips_total",
                                     (~ok).astype(jnp.float32), kind="counter")
-        # span-tracing phase mark: the optimizer phase closes when the new
-        # opt state is materialized (forward_solve / implicit_backward marks
-        # fire from inside the implicit fixed point)
-        obs_tracing.phase_done("optimizer", opt.step)
         return new_state, metrics
 
     return train_step

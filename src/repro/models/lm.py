@@ -433,13 +433,14 @@ def _apply_deq(params, x_emb, cfg, ctx, positions, caches, cache_index, train,
     if caches is None:
         def f(p, xin, z):
             x_in, pos = xin
-            h = z
-            for j in range(d.num_blocks):
-                pj = jax.tree_util.tree_map(lambda a: a[j], p["blocks"])
-                h, _, _ = apply_unit(kind, pj, h, cfg, ctx, pos,
-                                     None, None, p.get("shared"))
-            return ctx.constrain(x_in + (h - z),
-                                 ("batch", "seq_res", "embed_act"))
+            with jax.named_scope("deq_block"):
+                h = z
+                for j in range(d.num_blocks):
+                    pj = jax.tree_util.tree_map(lambda a: a[j], p["blocks"])
+                    h, _, _ = apply_unit(kind, pj, h, cfg, ctx, pos,
+                                         None, None, p.get("shared"))
+                return ctx.constrain(x_in + (h - z),
+                                     ("batch", "seq_res", "embed_act"))
 
         # cold start AT the injection: f(x) = x + C(x) is one free Picard
         # step, and the solve stays input-anchored even when a random-init
@@ -463,13 +464,14 @@ def _apply_deq(params, x_emb, cfg, ctx, positions, caches, cache_index, train,
     # against the frozen cache, then refresh the cache once at z*.
     def f_dec(p, xin, z):
         x_in, pos, cch, cidx = xin
-        h = z
-        for j in range(d.num_blocks):
-            pj = jax.tree_util.tree_map(lambda a: a[j], p["blocks"])
-            cj = jax.tree_util.tree_map(lambda a: a[j], cch["deq"])
-            h, _, _ = apply_unit(kind, pj, h, cfg, ctx, pos, cj,
-                                 cidx, p.get("shared"))
-        return x_in + (h - z)
+        with jax.named_scope("deq_block"):
+            h = z
+            for j in range(d.num_blocks):
+                pj = jax.tree_util.tree_map(lambda a: a[j], p["blocks"])
+                cj = jax.tree_util.tree_map(lambda a: a[j], cch["deq"])
+                h, _, _ = apply_unit(kind, pj, h, cfg, ctx, pos, cj,
+                                     cidx, p.get("shared"))
+            return x_in + (h - z)
 
     z0 = x_emb
     if active is not None:

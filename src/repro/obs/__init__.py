@@ -10,12 +10,13 @@ Three pillars, each usable alone:
   * :mod:`repro.obs.tape` — the fixed-size per-iteration
     :class:`~repro.obs.tape.SolveTape` (residual norm, step size,
     qN-ring occupancy) every solver threads through its loop state.
-  * :mod:`repro.obs.tracing` — timed spans emitting Chrome-trace /
-    Perfetto JSON, with ``phase_done`` marks for phases inside jit.
+  * :mod:`repro.obs.tracing` — host spans that land in profiler traces
+    and, while enabled, in Chrome-trace / Perfetto JSON.  Phases inside
+    jit are ``jax.named_scope`` names on the program's ops instead.
 
-The bridge and the tracer are gated at TRACE time: :func:`enable` before
-the first jitted call you want instrumented.  With both switches off
-(the default) compiled programs carry zero observability residue.
+The bridge is gated at TRACE time: :func:`enable` before the first jitted
+call you want instrumented.  With it off (the default) compiled programs
+carry zero observability residue; the span recorder is host-only.
 """
 
 from __future__ import annotations
@@ -24,14 +25,14 @@ from repro.obs import metrics, tape, tracing
 from repro.obs.metrics import (MetricsRegistry, default_registry,
                                emit_scalar, record_backward, record_solve)
 from repro.obs.tape import SolveTape, empty_tape, tape_record, tape_summary
-from repro.obs.tracing import TraceRecorder, default_recorder, phase_done, span
+from repro.obs.tracing import TraceRecorder, default_recorder, span
 
 __all__ = [
     "metrics", "tape", "tracing",
     "MetricsRegistry", "default_registry", "emit_scalar",
     "record_solve", "record_backward",
     "SolveTape", "empty_tape", "tape_record", "tape_summary",
-    "TraceRecorder", "default_recorder", "span", "phase_done",
+    "TraceRecorder", "default_recorder", "span",
     "enable", "disable", "status",
 ]
 

@@ -1,32 +1,22 @@
-"""Span tracing: lightweight timed spans emitting Chrome-trace JSON.
+"""Span tracing: host spans on the profiler's clock, plus a Chrome-trace
+recorder.
 
-The recorder collects events in the `Trace Event Format` consumed by
-Perfetto / chrome://tracing: ``{"traceEvents": [...]}`` with ``B``/``E``
-span pairs for host-side phases and complete ``X`` events for phases
-whose *end* is observed from inside compiled code.
+:func:`span` (``with span("train_step", step=i): ...``) always opens a
+``jax.profiler.TraceAnnotation`` of that name and arguments, so the span
+lands in any profiler trace (``jax.profiler.trace`` / ``start_trace``) on
+the same clock as the device ops it dispatched; with no profiler running
+that costs one TraceMe check.  While tracing is enabled
+(:func:`set_enabled`) the span also records a ``B``/``E`` pair in the
+`Trace Event Format` consumed by Perfetto / chrome://tracing
+(``{"traceEvents": [...]}``), which the launchers' ``--trace-out`` writes.
+Nest freely.
 
-Two ways to mark time:
+Phases inside compiled code are not marked here: the program names them
+with ``jax.named_scope`` (``deq_solve``, ``deq_block``, ``qn_update``,
+``implicit_backward``), which reaches the device ops of a profiler trace.
 
-  * :func:`span` — a host-side context manager (``with span("train_step",
-    step=i): ...``) emitting a B/E pair.  Nest freely.
-
-  * :func:`phase_done` — for phases *inside* a jitted function, where a
-    begin marker is unobservable (XLA schedules the program as a whole).
-    Call it at trace time with arrays the phase produces; when those
-    values materialize, a ``jax.debug.callback`` fires on the host and an
-    ``X`` event is recorded spanning from the previous phase boundary
-    (the enclosing span's start, or the last phase end) to now.  Within
-    one enclosing span the phases therefore tile the wall time:
-    ``forward_solve`` ends when its stats are ready, ``implicit_backward``
-    covers ready-to-ready, and so on.
-
-All events share one pid and a single synthetic tid so nesting is decided
-purely by time containment — callbacks may run on worker threads, and
-using real thread ids would scatter spans across trace rows.
-
-Like the metrics bridge, the enabled switch is consulted at TRACE time:
-enable tracing before the first call of a jitted function you want phase
-marks from.
+All recorded events share one pid and a single synthetic tid so nesting
+is decided purely by time containment.
 """
 
 from __future__ import annotations
@@ -37,8 +27,10 @@ import threading
 import time
 from contextlib import contextmanager
 
+import jax
+
 __all__ = ["TraceRecorder", "default_recorder", "set_enabled", "enabled",
-           "span", "instant", "phase_done", "write", "clear"]
+           "span", "instant", "write", "clear"]
 
 _PID = os.getpid()
 _TID = 1
@@ -49,10 +41,6 @@ class TraceRecorder:
         self._lock = threading.Lock()
         self._events: list[dict] = []
         self._t0 = time.perf_counter()
-        # the last phase boundary: start of the innermost open span, or the
-        # end of the most recent phase/span — phase_done events span from
-        # here to "now"
-        self._anchor: float | None = None
 
     def _now(self) -> float:
         return (time.perf_counter() - self._t0) * 1e6  # µs
@@ -65,33 +53,19 @@ class TraceRecorder:
 
     @contextmanager
     def span(self, name: str, **args):
-        t = self._now()
-        self._append({"name": name, "ph": "B", "ts": t, "pid": _PID,
-                      "tid": _TID, **({"args": args} if args else {})})
-        prev_anchor, self._anchor = self._anchor, t
+        self._append({"name": name, "ph": "B", "ts": self._now(),
+                      "pid": _PID, "tid": _TID,
+                      **({"args": args} if args else {})})
         try:
             yield
         finally:
-            t1 = self._now()
-            self._append({"name": name, "ph": "E", "ts": t1, "pid": _PID,
-                          "tid": _TID})
-            # phases after this span anchor at its end, not inside it
-            self._anchor = t1 if prev_anchor is not None else None
+            self._append({"name": name, "ph": "E", "ts": self._now(),
+                          "pid": _PID, "tid": _TID})
 
     def instant(self, name: str, **args) -> None:
         self._append({"name": name, "ph": "i", "s": "t", "ts": self._now(),
                       "pid": _PID, "tid": _TID,
                       **({"args": args} if args else {})})
-
-    def phase_done(self, name: str, **args) -> None:
-        """Record a complete X event ending now, starting at the previous
-        phase boundary (see module docstring)."""
-        t = self._now()
-        t0 = self._anchor if self._anchor is not None else t
-        self._append({"name": name, "ph": "X", "ts": t0,
-                      "dur": max(t - t0, 0.0), "pid": _PID, "tid": _TID,
-                      **({"args": args} if args else {})})
-        self._anchor = t
 
     # -- export ------------------------------------------------------------
 
@@ -119,7 +93,6 @@ class TraceRecorder:
     def clear(self) -> None:
         with self._lock:
             self._events.clear()
-        self._anchor = None
 
 
 _RECORDER = TraceRecorder()
@@ -141,34 +114,19 @@ def enabled() -> bool:
 
 @contextmanager
 def span(name: str, **args):
-    """Host-side timed span on the default recorder; no-op when disabled."""
-    if not _ENABLED:
-        yield
-        return
-    with _RECORDER.span(name, **args):
-        yield
+    """Host span: a profiler ``TraceAnnotation`` always, and a B/E pair on
+    the default recorder while tracing is enabled."""
+    with jax.profiler.TraceAnnotation(name, **args):
+        if not _ENABLED:
+            yield
+            return
+        with _RECORDER.span(name, **args):
+            yield
 
 
 def instant(name: str, **args) -> None:
     if _ENABLED:
         _RECORDER.instant(name, **args)
-
-
-def phase_done(name: str, *deps, **args) -> None:
-    """Trace-time phase mark for jitted code: plants a jax.debug.callback
-    on ``deps`` (arrays the phase produces) that closes the phase when they
-    are ready. No-op — zero trace residue — when tracing is disabled."""
-    if not _ENABLED:
-        return
-    if not deps:
-        _RECORDER.phase_done(name, **args)
-        return
-    import jax
-
-    def cb(*_):
-        _RECORDER.phase_done(name, **args)
-
-    jax.debug.callback(cb, *deps)
 
 
 def write(path: str) -> dict:

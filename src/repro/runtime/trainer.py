@@ -147,12 +147,6 @@ class Trainer:
                     batch = next(batches)
                 with obs_tracing.span("train_step", step=i + 1):
                     state, metrics = self._train_step(state, batch)
-                    if obs_tracing.enabled():
-                        # tracing is an opted-in diagnostic mode: flush the
-                        # step's phase_done callbacks so the in-jit phases
-                        # nest inside this host span (costs one sync/step,
-                        # paid ONLY while tracing)
-                        jax.block_until_ready(metrics)
                 n_since += 1
                 if (i + 1) % log_every == 0 or i + 1 == steps:
                     # the interval's ONE host sync: wait for the step's

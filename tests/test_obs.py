@@ -249,9 +249,7 @@ def test_chrome_trace_schema_and_nesting():
     with obs_tracing.span("outer", step=1):
         with obs_tracing.span("inner"):
             pass
-        y = jax.jit(lambda v: v * 2)(jnp.ones((5,)))
-        obs_tracing.phase_done("compute", y)
-        jax.block_until_ready(y)
+        jax.block_until_ready(jax.jit(lambda v: v * 2)(jnp.ones((5,))))
     obs_tracing.instant("tick")
 
     trace = obs_tracing.default_recorder().to_chrome_trace()
@@ -265,13 +263,14 @@ def test_chrome_trace_schema_and_nesting():
     begins = [e for e in ev if e["ph"] == "B"]
     ends = [e for e in ev if e["ph"] == "E"]
     assert len(begins) == len(ends) == 2
-    xs = [e for e in ev if e["ph"] == "X"]
-    assert len(xs) == 1 and xs[0]["dur"] >= 0
-    # the X phase is contained in the outer span's window
-    outer_b = next(e for e in begins if e["name"] == "outer")
-    outer_e = next(e for e in ends if e["name"] == "outer")
-    assert outer_b["ts"] <= xs[0]["ts"]
-    assert xs[0]["ts"] + xs[0]["dur"] <= outer_e["ts"] + 1e-3
+    assert [e["ph"] for e in ev if e["ph"] != "M"] == ["B", "B", "E", "E",
+                                                       "i"]
+    # the inner span nests in the outer one's window
+    at = {(e["name"], e["ph"]): e["ts"] for e in ev if e["ph"] in "BE"}
+    assert at["outer", "B"] <= at["inner", "B"] <= at["inner", "E"] \
+        <= at["outer", "E"]
+    assert next(e for e in begins if e["name"] == "outer")["args"] == {
+        "step": 1}
     # metadata events name the process/thread for Perfetto
     assert {e["name"] for e in ev if e["ph"] == "M"} == {
         "process_name", "thread_name"}
@@ -279,9 +278,39 @@ def test_chrome_trace_schema_and_nesting():
 
 def test_tracing_disabled_is_silent():
     with obs_tracing.span("ghost"):
-        obs_tracing.phase_done("phantom")
+        with obs_tracing.span("phantom", step=2):
+            pass
         obs_tracing.instant("nope")
     assert obs_tracing.default_recorder().events() == []
+
+
+def _host_events(logdir) -> list:
+    """(name, stats) of every event on the host planes of the profiler
+    trace written under ``logdir``."""
+    from pathlib import Path
+
+    from jax.profiler import ProfileData
+
+    (pb,) = Path(logdir).rglob("*.xplane.pb")
+    pd = ProfileData.from_file(str(pb))
+    return [(ev.name, dict(ev.stats)) for plane in pd.planes
+            if plane.name.startswith("/host") for line in plane.lines
+            for ev in line.events]
+
+
+@pytest.mark.parametrize("recording", [False, True])
+def test_span_lands_in_profiler_trace(tmp_path, recording):
+    """A span is a profiler annotation whether or not the Chrome-trace
+    recorder is on: it appears on the host plane with its arguments."""
+    obs_tracing.set_enabled(recording)
+    with jax.profiler.trace(str(tmp_path)):
+        with obs_tracing.span("obs_probe_span", step=7):
+            jax.block_until_ready(jnp.ones((3,)) * 2)
+    hits = [st for name, st in _host_events(tmp_path)
+            if name == "obs_probe_span"]
+    assert hits == [{"step": 7}]
+    assert len(obs_tracing.default_recorder().events()) == (
+        2 if recording else 0)
 
 
 # ---------------------------------------------------------------------------
