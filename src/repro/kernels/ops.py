@@ -47,7 +47,9 @@ The qn ops also keep trace-time stream statistics
 (``reset_qn_stream_stats``/``qn_stream_stats``): inside a ``lax.while_loop``
 the body traces once, so the counters report per-iteration call/byte costs —
 the bench harness uses them to verify a Broyden step performs exactly one
-fused U/V pass.
+fused U/V pass.  The prefill attention kernel likewise records its tile
+plan when traced (counter ``attention_grid_steps``; gauges
+``attention_head_block``, ``attention_block_q``, ``attention_block_k``).
 
 Training differentiability: the Pallas flash-attention here implements the
 forward only; ``attention`` wraps it in a custom_vjp whose backward
@@ -72,8 +74,10 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels import ref
 from repro.obs import metrics as obs_metrics
 from repro.kernels.flash_attention import (
+    FlashPlan,
     decode_attention_pallas,
     flash_attention_pallas,
+    flash_plan,
 )
 from repro.kernels.flash_xla import flash_attention_xla
 from repro.kernels.qn_apply import (
@@ -399,6 +403,16 @@ def _heads_axis(num_heads: int, num_kv_heads: int) -> str | None:
     return "heads_act"
 
 
+def _record_attention_plan(plan: FlashPlan) -> None:
+    """Trace-time record of the prefill kernel's tile plan: grid steps
+    summed over traced calls, and the last call's head block and tiles."""
+    reg = obs_metrics.default_registry()
+    reg.counter("attention_grid_steps").inc(plan.grid_steps)
+    reg.gauge("attention_head_block").set(plan.hb)
+    reg.gauge("attention_block_q").set(plan.blk_q)
+    reg.gauge("attention_block_k").set(plan.blk_k)
+
+
 def _attention_fwd_impl(q, k, v, kv_length, causal, scale, impl):
     if impl == "ref":
         return ref.attention_ref(q, k, v, causal=causal, kv_length=kv_length,
@@ -407,6 +421,8 @@ def _attention_fwd_impl(q, k, v, kv_length, causal, scale, impl):
         kv_length = jnp.full((q.shape[0],), k.shape[1], jnp.int32)
 
     def local(q, k, v, kv_length):
+        # under a mesh these are the device's own heads and rows
+        _record_attention_plan(flash_plan(q.shape, k.shape, q.dtype))
         return flash_attention_pallas(
             q, k, v, kv_length, causal=causal, scale=scale,
             interpret=(impl == "pallas_interpret"))

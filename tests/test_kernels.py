@@ -7,13 +7,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import (
     decode_attention_pallas,
     flash_attention_pallas,
+    flash_plan,
 )
 from repro.kernels.flash_xla import flash_attention_xla
 from repro.kernels.rmsnorm import rmsnorm_pallas
+from repro.obs import metrics as obs_metrics
 
 KEY = jax.random.PRNGKey(0)
 
@@ -296,41 +299,153 @@ def test_rmsnorm_pallas_vs_oracle(shape, dtype):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("b,s,h,kv,hd,causal", [
+def _attn_cases(*cases):
+    """Cases ``(b, s, h, kv, hd, causal[, t])``; ``t`` (kv length, default
+    ``s``) shows in the id only where it differs from ``s``."""
+    out = []
+    for c in cases:
+        b, s, h, kv, hd, causal, t = c if len(c) == 7 else c + (c[1],)
+        tag = "-".join(map(str, (b, s, h, kv, hd, causal)))
+        out.append(pytest.param(b, s, t, h, kv, hd, causal,
+                                id=tag if t == s else f"{tag}-t{t}"))
+    return out
+
+
+def _qkv(seed, b, s, t, h, kv, hd, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.fold_in(KEY, seed), 4)
+    return (jax.random.normal(ks[0], (b, s, h, hd), dtype),
+            jax.random.normal(ks[1], (b, t, kv, hd), dtype),
+            jax.random.normal(ks[2], (b, t, kv, hd), dtype), ks[3])
+
+
+@pytest.mark.parametrize("b,s,t,h,kv,hd,causal", _attn_cases(
     (1, 128, 4, 4, 64, True),
     (2, 256, 4, 2, 64, True),
     (1, 128, 8, 8, 64, False),
     (2, 128, 4, 1, 128, True),
-])
-def test_flash_attention_pallas_vs_oracle(b, s, h, kv, hd, causal):
-    ks = jax.random.split(jax.random.fold_in(KEY, s * h), 3)
-    q = jax.random.normal(ks[0], (b, s, h, hd), jnp.float32)
-    k = jax.random.normal(ks[1], (b, s, kv, hd), jnp.float32)
-    v = jax.random.normal(ks[2], (b, s, kv, hd), jnp.float32)
-    want = ref.attention_ref(q, k, v, causal=causal)
+    (1, 512, 36, 36, 64, True),     # the train cell's heads: 2 heads a block
+    (2, 128, 5, 5, 64, True),       # odd head count: all heads a block
+    (2, 256, 8, 2, 64, True),       # GQA: a block spans both kv groups
+    (2, 128, 8, 8, 64, True, 384),  # chunked prefill: q is the kv tail
+    (1, 1152, 2, 2, 64, True),      # past the whole-sequence cap: tiled
+))
+def test_flash_attention_pallas_vs_oracle(b, s, t, h, kv, hd, causal):
+    q, k, v, _ = _qkv(s * h, b, s, t, h, kv, hd)
+    want = ref.attention_ref(q, k, v, causal=causal, q_offset=t - s)
     got = flash_attention_pallas(q, k, v, None, causal=causal, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-3, atol=2e-3)
 
 
-@pytest.mark.parametrize("b,s,h,kv,hd,causal", [
+@pytest.mark.parametrize("b,s,t,h,kv,hd,causal", _attn_cases(
     (2, 100, 4, 4, 64, True),
     (3, 300, 4, 2, 64, True),
     (1, 77, 8, 2, 64, False),
-])
-def test_flash_attention_ragged_length_kv_masking(b, s, h, kv, hd, causal):
+    (2, 200, 36, 36, 64, True),
+    (2, 90, 5, 5, 64, True),
+    (2, 100, 8, 2, 64, True, 333),
+    (2, 1100, 2, 2, 64, True),
+))
+def test_flash_attention_ragged_length_kv_masking(b, s, t, h, kv, hd, causal):
     """Prompt lengths off the 128-row block are padded and the valid kv
     length masks both the padding and each row's own tail."""
-    ks = jax.random.split(jax.random.fold_in(KEY, s + 7 * h), 4)
-    q = jax.random.normal(ks[0], (b, s, h, hd), jnp.float32)
-    k = jax.random.normal(ks[1], (b, s, kv, hd), jnp.float32)
-    v = jax.random.normal(ks[2], (b, s, kv, hd), jnp.float32)
-    kv_len = jax.random.randint(ks[3], (b,), s // 2, s + 1)
-    want = ref.attention_ref(q, k, v, causal=causal, kv_length=kv_len)
+    q, k, v, kl = _qkv(s + 7 * h, b, s, t, h, kv, hd)
+    kv_len = jax.random.randint(kl, (b,), t // 2, t + 1)
+    want = ref.attention_ref(q, k, v, causal=causal, kv_length=kv_len,
+                             q_offset=t - s)
     got = ops.attention(q, k, v, causal=causal, kv_length=kv_len,
                         impl="pallas_interpret")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("s,t,causal,blk", [
+    (256, 256, True, 128), (200, 328, True, 64), (256, 256, False, 128),
+    (320, 320, True, 128)])
+def test_flash_attention_pinned_tiles_vs_oracle(s, t, causal, blk):
+    """Pinned flash tiles: the online softmax across kv blocks, causal
+    blocks skipped with their copies clamped, chunked prefill's offset."""
+    q, k, v, kl = _qkv(s + t, 2, s, t, 4, 2, 64)
+    kv_len = jax.random.randint(kl, (2,), t // 2, t + 1)
+    plan = flash_plan(q.shape, k.shape, q.dtype, block_q=blk, block_k=blk)
+    assert plan.grid[2] > 1 and plan.grid[3] > 1
+    want = ref.attention_ref(q, k, v, causal=causal, kv_length=kv_len,
+                             q_offset=t - s)
+    got = flash_attention_pallas(q, k, v, kv_len, causal=causal,
+                                 block_q=blk, block_k=blk, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-3, atol=2e-3)
+
+
+# the train cell's prefill (B=8, S=512, 36 heads x 64, bf16), the same with
+# GQA, odd heads, long and chunked prompts, f32 and hd 128
+_PLAN_SHAPES = [
+    ((8, 512, 36, 64), (8, 512, 36, 64), jnp.bfloat16),
+    ((8, 512, 32, 64), (8, 512, 8, 64), jnp.bfloat16),
+    ((4, 300, 5, 64), (4, 300, 5, 64), jnp.bfloat16),
+    ((8, 512, 9, 64), (8, 512, 9, 64), jnp.bfloat16),
+    ((1, 4096, 36, 64), (1, 4096, 36, 64), jnp.bfloat16),
+    ((16, 128, 36, 64), (16, 1152, 36, 64), jnp.bfloat16),
+    ((2, 2048, 8, 128), (2, 2048, 2, 128), jnp.bfloat16),
+    ((1, 1152, 2, 64), (1, 1152, 2, 64), jnp.float32),
+    ((1, 32768, 16, 64), (1, 32768, 16, 64), jnp.bfloat16),
+]
+
+
+def test_flash_plan_at_the_train_cell():
+    plan = flash_plan((8, 512, 36, 64), (8, 512, 36, 64), jnp.bfloat16)
+    assert plan.grid_steps <= 300          # 4,608 with 128 x 128 tiles
+    assert (plan.hb * 64) % 128 == 0
+    assert plan.grid[2:] == (1, 1)         # the whole sequence in one block
+
+
+@pytest.mark.parametrize("q_shape,k_shape,dtype", _PLAN_SHAPES)
+def test_flash_plan_vmem_under_budget(q_shape, k_shape, dtype):
+    plan = flash_plan(q_shape, k_shape, dtype)
+    assert plan.vmem_bytes <= fa._VMEM_BUDGET
+    assert plan.s_pad >= q_shape[1] and plan.t_pad >= k_shape[1]
+    assert plan.s_pad % plan.blk_q == 0 and plan.t_pad % plan.blk_k == 0
+    assert plan.blk_q % 16 == 0 and plan.blk_k % 16 == 0
+
+
+@pytest.mark.parametrize("q_shape,k_shape,dtype",
+                         [c for c in _PLAN_SHAPES if c[1][2] % 2 == 0])
+def test_flash_plan_divides_local_heads_under_two_way_split(
+        q_shape, k_shape, dtype):
+    """Under a 2-way heads split each device plans its own heads."""
+    (b, s, h, hd), (_, t, kv, _) = q_shape, k_shape
+    plan = flash_plan((b, s, h // 2, hd), (b, t, kv // 2, hd), dtype)
+    assert (h // 2) % plan.hb == 0 and plan.grid[1] == h // 2 // plan.hb
+    assert plan.hb == h // 2 or (plan.hb * hd) % 128 == 0
+
+
+def test_flash_plan_head_blocks():
+    # no lane-dense divisor: one block holds the whole head axis
+    odd = flash_plan((1, 128, 5, 64), (1, 128, 5, 64), jnp.bfloat16)
+    assert (odd.hb, odd.kvb) == (5, 5)
+    gqa = flash_plan((1, 256, 8, 64), (1, 256, 2, 64), jnp.bfloat16)
+    assert (gqa.hb, gqa.kvb) == (8, 2)
+    assert flash_plan((1, 128, 4, 128), (1, 128, 4, 128), jnp.float32).hb == 1
+
+
+def test_attention_grid_steps_counts_traced_calls():
+    reg = obs_metrics.default_registry()
+    reg.counter("attention_grid_steps").value = 0.0
+    q1, k1, v1, _ = _qkv(1, 2, 128, 128, 4, 4, 64)
+    q2, k2, v2, _ = _qkv(2, 1, 64, 192, 8, 2, 64)
+
+    def f(q1, k1, v1, q2, k2, v2):
+        return (ops.attention(q1, k1, v1, impl="pallas_interpret"),
+                ops.attention(q2, k2, v2, impl="pallas_interpret"))
+
+    jax.jit(f).lower(q1, k1, v1, q2, k2, v2)
+    p1 = flash_plan(q1.shape, k1.shape, q1.dtype)
+    p2 = flash_plan(q2.shape, k2.shape, q2.dtype)
+    assert reg.counter("attention_grid_steps").value == (
+        p1.grid_steps + p2.grid_steps)
+    assert reg.gauge("attention_head_block").value == p2.hb
+    assert reg.gauge("attention_block_q").value == p2.blk_q
+    assert reg.gauge("attention_block_k").value == p2.blk_k
 
 
 @pytest.mark.parametrize("b,t,h,kv,hd", [(2, 256, 4, 4, 64), (1, 512, 8, 2, 64),

@@ -109,13 +109,26 @@ def test_broyden_step_compiles(one_chip, width):
     assert "tpu_custom_call" in txt
 
 
-@pytest.mark.parametrize("seq", [512, 300])
-def test_flash_attention_compiles(one_chip, seq):
-    qkv = [_sds(one_chip, (B, seq, HEADS, HD), jnp.bfloat16)] * 3
-    lens = _sds(one_chip, (B,), jnp.int32)
+@pytest.mark.parametrize("seq,kv_seq,heads,lengths", [
+    pytest.param(512, 512, HEADS, True, id="512"),
+    pytest.param(300, 300, HEADS, True, id="300"),
+    # the train cell's exact call: whole-sequence blocks, no kv_length
+    pytest.param(512, 512, HEADS, False, id="512-train-cell"),
+    # a serving prefill past the whole-sequence cap: flash tiles, clamped
+    # causal kv copies
+    pytest.param(1152, 1152, HEADS, True, id="1152-tiled"),
+    # chunked prefill: 128 new rows against a 1152-row kv axis
+    pytest.param(128, 1152, HEADS, True, id="128-chunk-of-1152"),
+    # a 4-way heads split: 9 local heads have no lane-dense divisor
+    pytest.param(512, 512, HEADS // 4, True, id="512-9-heads")])
+def test_flash_attention_compiles(one_chip, seq, kv_seq, heads, lengths):
+    q = _sds(one_chip, (B, seq, heads, HD), jnp.bfloat16)
+    kv = _sds(one_chip, (B, kv_seq, heads, HD), jnp.bfloat16)
+    lens = [_sds(one_chip, (B,), jnp.int32)] if lengths else []
     txt = _compiled_text(
-        lambda q, k, v, ln: ops.attention(q, k, v, causal=True, kv_length=ln,
-                                          impl="pallas"), *qkv, lens)
+        lambda q, k, v, *ln: ops.attention(q, k, v, causal=True,
+                                           kv_length=ln[0] if ln else None,
+                                           impl="pallas"), q, kv, kv, *lens)
     assert "tpu_custom_call" in txt
 
 
