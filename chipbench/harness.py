@@ -47,6 +47,19 @@ def metric_names(bench: dict, workload: str, section: str) -> list[dict]:
             if "workloads" not in m or workload in m["workloads"]]
 
 
+def check_mesh(wl: dict, mix: dict) -> None:
+    """Refuse, before any set-up, a cell whose traffic runs on a mesh of
+    another number of chips than the cell asks for (no mesh: one)."""
+    from chipbench.traffic import chips_needed
+
+    need = chips_needed(mix)
+    if need != wl["chips"]:
+        print(f"chipbench: {wl['name']} asks for {wl['chips']} chip(s), but "
+              f"its traffic {wl['traffic']!r} runs on {need}. No result.",
+              file=sys.stderr)
+        raise SystemExit(2)
+
+
 def require_chips(n: int):
     """The accelerator devices, or exit non-zero with no result."""
     import jax

@@ -6,6 +6,11 @@ the first ``checked_steps`` steps (the readings the check compares); the
 window then continues the same state.  The window dispatches steps with at
 most ``in_flight`` unfinished, and ends with ``block_until_ready``; the
 rate is every token of every step dispatched over the whole window.
+
+Where the mix names a mesh, the weights, the state and each batch are made
+in the layouts the program's sharded path gives them (its ``Trainer``'s),
+so that no chip holds the whole model or optimizer state, and the
+reference is spread over the same chips.
 """
 
 from __future__ import annotations
@@ -33,12 +38,15 @@ B1 = 0.9  # the program's AdamW first-moment decay
 
 
 def train_config(mix: dict, cfg) -> TrainConfig:
+    """The program's training settings for the mix; on a mesh with ZeRO-1,
+    as ``launch/train.py --mesh`` sets it."""
     return TrainConfig(
         steps=10 ** 9, global_batch=mix["batch"], seq_len=mix["seq"],
         lr=mix["lr"], warmup_steps=mix["warmup_steps"],
         schedule=mix["schedule"], z_loss=mix["z_loss"],
         clip_norm=mix["clip_norm"], weight_decay=mix["weight_decay"],
-        deq_carry=mix["deq_carry"], qn_dtype=cfg.deq.qn_dtype, zero1=False)
+        deq_carry=mix["deq_carry"], qn_dtype=cfg.deq.qn_dtype,
+        zero1=traffic.mesh_shape(mix) is not None)
 
 
 @jax.jit
@@ -53,29 +61,40 @@ def _change_norms(new, old):
         lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32), new, old))
 
 
+def placed(fn, shardings):
+    """``fn`` jitted, its outputs laid out as ``shardings`` (None: where
+    JAX puts them, on one device)."""
+    if shardings is None:
+        return jax.jit(fn)
+    return jax.jit(fn, out_shardings=shardings)
+
+
 def build(run: Run, step_wrapper=None):
     """The jitted step, the state after the checked steps, and the
     program's readings of them."""
     spec, mix = run.spec, run.mix
     cfg = program.model_config(spec)
     tcfg = train_config(mix, cfg)
-    ctx = program.ctx()
-    params = make_params(spec, run.seed)
+    ctx = program.ctx(cfg, mix, run.devices)
+    trainer = Trainer(cfg, tcfg, ctx)
+    pshard = None if ctx.mesh is None else program.param_shardings(cfg, ctx)
+    params = make_params(spec, run.seed, shardings=pshard)
     program.check_layout(cfg, params)
-    step = Trainer(cfg, tcfg, ctx)._train_step
+    step = trainer._train_step
     if step_wrapper is not None:
         step = step_wrapper(step)
 
-    @jax.jit
     def initial_state(p):
         return TrainState(jnp.zeros((), jnp.int32), p, adamw_init(p),
                           program.lm.deq_solve_carry(cfg, mix["batch"],
                                                      mix["seq"]),
                           jnp.zeros((), jnp.int32))
 
-    state = initial_state(params)
+    state = placed(initial_state, trainer.state_sharding)(params)
     del params
-    batch_at = jax.jit(traffic.train_batch_fn(mix, spec.vocab))
+    bshard = program.batch_sharding(ctx)
+    batch_at = placed(traffic.train_batch_fn(mix, spec.vocab),
+                      None if bshard is None else (bshard, bshard))
     data_key = jax.random.fold_in(key_from_seed(run.seed), 1)
 
     def feed(i):
@@ -95,7 +114,7 @@ def build(run: Run, step_wrapper=None):
             readings["outside"] = {
                 k: m / (1.0 - B1)
                 for k, m in model_ref.outside_group(state.opt.mu).items()}
-    p0 = make_params(spec, run.seed)
+    p0 = make_params(spec, run.seed, shardings=pshard)
     readings["change"] = [float(x) for x in _change_norms(state.params, p0)]
     del p0
     return step, state, feed, readings, cfg
@@ -152,9 +171,10 @@ def reference_readings(run: Run, precision: str, rows: slice | None = None,
     tcfg = {k: mix[k] for k in ("lr", "warmup_steps", "z_loss", "clip_norm",
                                 "weight_decay")}
     tcfg.update(b1=B1, b2=0.95, eps=1e-8)
-    return model_ref.train_readings(lambda: make_params(spec, run.seed),
-                                    batches, spec, tcfg, precision,
-                                    backward or spec.backward)
+    return model_ref.train_readings(
+        lambda shardings: make_params(spec, run.seed, shardings=shardings),
+        batches, spec, tcfg, precision, backward or spec.backward,
+        run.devices)
 
 
 def gaps(prog: dict, ref: dict) -> dict:

@@ -53,3 +53,14 @@ def idle_share(run) -> float | None:
     if tr is None or tr.window_s <= 0 or tr.busy_s <= 0:
         return None
     return 100.0 * (1.0 - tr.busy_s / tr.window_s)
+
+
+def train_scopes(run) -> dict:
+    """``scopes.train_split`` of the traced training window; ``{}`` where
+    the run has no trace."""
+    from chipbench.scopes import train_split
+
+    if run.reduced_trace is None:
+        return {}
+    return train_split(run.reduced_trace, sum(run.counters.get("deq_steps",
+                                                               [])))

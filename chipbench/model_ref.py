@@ -12,6 +12,10 @@ the program.  ``precision="fp8"`` is the control: every matrix product
 takes its operands through float8 (e4m3, one scale per tensor), the next
 precision below the bfloat16 the configuration states.
 
+On several devices the training reference spreads its parameters, gradients
+and moments over them by a rule of its own (``layout``); the mathematics
+and its precision are the same as on one.
+
 The model (as the program runs it; departures from the published models
 are listed in each configuration file):
 
@@ -31,6 +35,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from chipbench.spec import ModelSpec
 
@@ -287,17 +293,57 @@ def lr_at(step: int, tcfg: dict) -> float:
 
 
 ROWS_PER_CHUNK = 2
+CHIPS = "chips"  # the one axis of the reference's mesh
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
-def _grad(params, tokens, targets, z0, spec, z_loss, precision, backward):
+def mesh_over(devices) -> Mesh | None:
+    """A one-axis mesh over ``devices``; None for one device."""
+    if devices is None or len(devices) <= 1:
+        return None
+    return Mesh(np.asarray(devices), (CHIPS,))
+
+
+def leaf_sharding(shape, mesh: Mesh) -> NamedSharding:
+    """The reference's rule: a leaf of two or more dimensions is split along
+    its largest axis that the device count divides (the last of equals);
+    any other leaf is replicated."""
+    n = mesh.devices.size
+    axes = [i for i, d in enumerate(shape) if d % n == 0] \
+        if len(shape) >= 2 else []
+    spec = [None] * len(shape)
+    if axes:
+        spec[max(axes, key=lambda i: (shape[i], i))] = CHIPS
+    return NamedSharding(mesh, PartitionSpec(*spec))
+
+
+def layout(tree, mesh: Mesh):
+    """``leaf_sharding`` of every leaf of ``tree`` (arrays or shapes)."""
+    return jax.tree_util.tree_map(lambda a: leaf_sharding(a.shape, mesh),
+                                  tree)
+
+
+def _pin(tree, mesh: Mesh | None):
+    """``tree`` held to the reference's layout inside a jitted function;
+    unchanged on one device."""
+    if mesh is None:
+        return tree
+    return jax.tree_util.tree_map(
+        lambda a: jax.lax.with_sharding_constraint(
+            a, leaf_sharding(a.shape, mesh)), tree)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+def _grad(params, tokens, targets, z0, spec, z_loss, precision, backward,
+          mesh):
     """Mean loss, its gradient and ``z*``, the gradient summed over chunks
     of rows (rows are independent sequences and solves) so that the
     activations of few rows are live.  ``z0`` is the start of each row's
     solve: the embedding, or the last step's ``z*`` (the configured warm
     start from the iterate).  ``params`` come as stored (bfloat16) and
-    are worked on in float32."""
-    params = jax.tree_util.tree_map(lambda a: a.astype(F32), params)
+    are worked on in float32; on a ``mesh`` the float32 copy, each chunk's
+    gradient and their sum keep the reference's layout."""
+    params = _pin(jax.tree_util.tree_map(lambda a: a.astype(F32), params),
+                  mesh)
     n, rows = targets.size, targets.shape[0]
     chunk = min(ROWS_PER_CHUNK, rows)
 
@@ -307,10 +353,11 @@ def _grad(params, tokens, targets, z0, spec, z_loss, precision, backward):
             params, sl(tokens), sl(targets), sl(z0), spec, z_loss, precision,
             backward)
         return (acc[0] + val / n,
-                jax.tree_util.tree_map(lambda a, b: a + b / n, acc[1], g),
+                _pin(jax.tree_util.tree_map(lambda a, b: a + b / n, acc[1],
+                                            _pin(g, mesh)), mesh),
                 jax.lax.dynamic_update_slice_in_dim(acc[2], z, i * chunk, 0))
 
-    zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
+    zeros = _pin(jax.tree_util.tree_map(jnp.zeros_like, params), mesh)
     return jax.lax.fori_loop(0, rows // chunk, body,
                              (jnp.float32(0), zeros, jnp.zeros_like(z0)))
 
@@ -358,18 +405,30 @@ def outside_group(tree) -> dict:
 
 
 def train_readings(make_params0, batches, spec: ModelSpec, tcfg: dict,
-                   precision="f32", backward="shine_fallback") -> dict:
+                   precision="f32", backward="shine_fallback",
+                   devices=None) -> dict:
     """Follow the first ``len(batches)`` optimizer steps from the weights
-    ``make_params0()`` makes (made again at the end, for the change, so they
-    are not held during the steps).  The first step's solves start at the
-    embedding, each later one at the last step's ``z*``.
+    ``make_params0(shardings)`` makes (made again at the end, for the
+    change, so they are not held during the steps).  The first step's
+    solves start at the embedding, each later one at the last step's
+    ``z*``.  Over several ``devices`` the parameters, gradients and moments
+    are spread by ``layout`` (``shardings`` is that layout; None on one
+    device).
 
     Returns the loss of each step, the norm of each leaf of the first
     (clipped) gradient and the leaves of it outside the DEQ group, and the
     norm of each leaf's change over all the steps."""
-    params = make_params0()
-    mu = jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, F32), params)
-    nu = jax.tree_util.tree_map(jnp.zeros_like, mu)
+    mesh = mesh_over(devices)
+    shardings = None if mesh is None else layout(
+        jax.eval_shape(lambda: make_params0(None)), mesh)
+    params = make_params0(shardings)
+    if mesh is None:
+        mu = jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, F32), params)
+        nu = jax.tree_util.tree_map(jnp.zeros_like, mu)
+    else:
+        mu, nu = (jax.tree_util.tree_map(
+            lambda a: jnp.zeros(a.shape, F32, device=a.sharding), params)
+            for _ in range(2))
     items = tuple(sorted((k, v) for k, v in tcfg.items()
                          if isinstance(v, (int, float))))
     losses, first_grad, outside, z = [], None, None, None
@@ -378,7 +437,7 @@ def train_readings(make_params0, batches, spec: ModelSpec, tcfg: dict,
             if z is None:
                 z = params["embed"]["embedding"][tokens].astype(F32)
             lval, grads, z = _grad(params, tokens, targets, z, spec,
-                                   tcfg["z_loss"], precision, backward)
+                                   tcfg["z_loss"], precision, backward, mesh)
             params, clipped, mu, nu, _ = _adam(
                 params, grads, mu, nu, items, lr_at(i, tcfg),
                 float(i + 1))
@@ -390,7 +449,7 @@ def train_readings(make_params0, batches, spec: ModelSpec, tcfg: dict,
         del mu, nu, z
         change = [float(_diff_norm(a, b)) for a, b in zip(
             jax.tree_util.tree_leaves(params),
-            jax.tree_util.tree_leaves(make_params0()))]
+            jax.tree_util.tree_leaves(make_params0(shardings)))]
     return {"loss": losses, "grad": first_grad, "outside": outside,
             "change": change}
 

@@ -2,8 +2,9 @@
 language model (``repro``), its trainer step and its serving loop.
 
 Everything the benchmark takes from the program goes through here: the
-model configuration built from a configuration file, the jitted train step,
-the serving loop, and the counters they keep.
+model configuration built from a configuration file, the sharding context
+and layouts of a mesh the traffic names, the jitted train step, and the
+counters it keeps.
 """
 
 from __future__ import annotations
@@ -20,14 +21,19 @@ if str(REPO / "src") not in sys.path:
 
 from repro.configs.base import DEQSettings, ModelConfig  # noqa: E402
 from repro.configs.registry import get_config  # noqa: E402
+from repro.configs.shapes import SHAPES, make_ctx  # noqa: E402
 from repro.launch.compile_cache import use_persistent_cache  # noqa: E402
+from repro.launch.steps import param_shardings  # noqa: E402
 from repro.models import lm  # noqa: E402
 from repro.parallel.sharding import ShardCtx  # noqa: E402
 
 from chipbench.spec import ModelSpec  # noqa: E402
+from chipbench.traffic import mesh_shape  # noqa: E402
 
 __all__ = ["model_config", "check_layout", "use_persistent_cache", "ctx",
-           "lm"]
+           "device_mesh", "param_shardings", "batch_sharding", "lm", "AXES"]
+
+AXES = ("data", "model")
 
 
 def model_config(spec: ModelSpec) -> ModelConfig:
@@ -56,5 +62,28 @@ def check_layout(cfg: ModelConfig, params) -> None:
                          f"{need}")
 
 
-def ctx() -> ShardCtx:
-    return ShardCtx.for_mesh(None)
+def ctx(cfg: ModelConfig, mix: dict, devices) -> ShardCtx:
+    """The program's sharding context for the mix: none for a mix that names
+    no mesh; else the one ``launch/train.py --mesh DxM`` builds, over
+    ``devices``."""
+    shape = mesh_shape(mix)
+    if shape is None:
+        return ShardCtx.for_mesh(None)
+    # the rules ``launch/train.py`` takes: its ``train_4k`` shape's
+    return make_ctx(cfg, device_mesh(shape, devices), SHAPES["train_4k"])
+
+
+def device_mesh(shape: tuple[int, int], devices):
+    """The ``(data, model)`` mesh of ``shape`` over ``devices``, laid out as
+    the program's ``launch.mesh.make_device_mesh`` lays it out over the
+    devices present: every axis in GSPMD's automatic sharding mode."""
+    return jax.make_mesh(tuple(shape), AXES,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(AXES),
+                         devices=list(devices))
+
+
+def batch_sharding(ctx: ShardCtx):
+    """Where a ``(batch, seq)`` token array goes: the layout the program's
+    data pipeline (``data.pipeline.shard_batch``) gives it; None off a
+    mesh."""
+    return None if ctx.mesh is None else ctx.sharding(("batch", "seq"))

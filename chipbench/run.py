@@ -11,7 +11,9 @@ and each per-layer metric is read by ``chipbench/metrics/<metric>.py``.
 With ``--trace 0`` the result line holds the cell's end-to-end metrics;
 with ``--trace 1`` a profiler trace of the window gives its per-layer
 metrics.  The last line of standard output is one JSON object; a machine
-without the chips the cell asks for gets an exit code of 3 and no result.
+without the chips the cell asks for gets an exit code of 3 and no result,
+and a cell whose traffic names a mesh of another size than its chips an
+exit code of 2 and no result.
 """
 
 from __future__ import annotations
@@ -92,6 +94,9 @@ def execute(args, devices, job_override=None):
 def main(argv=None) -> int:
     args = parse(argv)
     wl, _ = harness.cell(args.workload)
+    from chipbench import traffic
+
+    harness.check_mesh(wl, traffic.load(wl["traffic"]))
     devices = harness.require_chips(wl["chips"])
     from chipbench import program
 

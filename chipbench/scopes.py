@@ -6,19 +6,17 @@ device op's name stack, ``jit(step)/jvp(deq_solve)/while/body/qn_update/
 mul:``, which the trace keeps as the ``tf_op`` stat of the op's event
 metadata.  ``jax.profiler.ProfileData`` does not expose metadata stats, so
 ``op_stacks`` reads them from the file's wire format with the standard
-library; the ops' times come from ``ProfileData``, as in ``trace.reduce``,
-and are clipped to the same window.
+library; ``trace.reduce`` gives each op its stack, and
+``Reduced.scope_seconds`` sums the ops' device time by scope.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 import re
 from pathlib import Path
 
-from chipbench.trace import (ANNOTATIONS, CONTAINERS, DEVICE_PLANE, OPS_LINE,
-                             base_name, short_name)
+from chipbench.trace import DEVICE_PLANE, OPS_LINE
 
 NAME_STACK_STAT = "tf_op"
 
@@ -138,67 +136,7 @@ def scope_names(stack: str) -> frozenset:
     return frozenset(out)
 
 
-@dataclasses.dataclass
-class ScopedOps:
-    ops: list[tuple[str, int, str]]   # (op name, device ns in window, stack)
-    n_devices: int
-
-    def seconds(self, *scopes: str) -> float:
-        """Device seconds, mean per chip, of the ops whose name stack holds
-        every one of ``scopes`` as a path segment (bare, or wrapped by a
-        transform as in ``jvp(deq_solve)``).  Control-flow containers are
-        left out: their bodies' ops are counted on their own.  0 where no op
-        carries the scopes."""
-        want = frozenset(scopes)
-        ns = sum(dur for name, dur, stack in self.ops
-                 if stack and want <= scope_names(stack)
-                 and base_name(name) not in CONTAINERS)
-        return ns * 1e-9 / self.n_devices
-
-    def found(self, scope: str) -> bool:
-        return any(scope in scope_names(stack) for _, _, stack in self.ops)
-
-
-def read(path: str | Path, annotations=ANNOTATIONS) -> ScopedOps:
-    """The device ops of one ``.xplane.pb`` file with their name stacks,
-    clipped to the window ``trace.reduce`` takes: first to last harness
-    annotation, else first to last op."""
-    from jax.profiler import ProfileData
-
-    pd = ProfileData.from_file(str(path))
-    stacks = op_stacks(path)
-    spans, planes = [], []
-    for plane in pd.planes:
-        if DEVICE_PLANE.match(plane.name):
-            lines = {line.name: line for line in plane.lines}
-            if OPS_LINE in lines:
-                evs = list(lines[OPS_LINE].events)
-                named = stacks.get(plane.name, [])
-                if [n for n, _ in named] != [e.name for e in evs]:
-                    named = [("", "")] * len(evs)
-                planes.append([(e, s) for e, (_, s) in zip(evs, named)])
-        elif plane.name.startswith("/host"):
-            spans += [(int(ev.start_ns), int(ev.end_ns))
-                      for line in plane.lines for ev in line.events
-                      if ev.name in annotations]
-    if not planes:
-        raise ValueError(f"{path}: no device plane with an '{OPS_LINE}' line")
-    if spans:
-        w0, w1 = min(s for s, _ in spans), max(e for _, e in spans)
-    else:
-        w0 = min(int(e.start_ns) for evs in planes for e, _ in evs)
-        w1 = max(int(e.end_ns) for evs in planes for e, _ in evs)
-    ops = []
-    for evs in planes:
-        for e, stack in evs:
-            s, t = max(int(e.start_ns), w0), min(int(e.end_ns), w1)
-            if t > s:
-                ops.append((short_name(e.name), t - s, stack))
-    return ScopedOps(ops, len(planes))
-
-
-def train_split(scoped: ScopedOps, busy_s: float,
-                iterations: float) -> dict[str, float | None]:
+def train_split(reduced, iterations: float) -> dict[str, float | None]:
     """The layer numbers of a traced training window (PERF.md §3):
 
     * ``solve_share``, ``backward_share``: device time of ``deq_solve`` and
@@ -208,17 +146,18 @@ def train_split(scoped: ScopedOps, busy_s: float,
       solve's one evaluation before its first iteration is in the block's
       share).
 
-    ``iterations`` is the window's forward iterations, the steps' summed
-    ``deq_steps``.  A number whose scope the trace lacks is None."""
+    ``reduced`` is the window's ``trace.Reduced``; ``iterations`` its
+    forward iterations, the steps' summed ``deq_steps``.  A number whose
+    scope the trace lacks is None."""
     def per(seconds, over, scale):
         return scale * seconds / over if seconds > 0 and over > 0 else None
 
+    busy_s, seconds = reduced.busy_s, reduced.scope_seconds
     return {
-        "solve_share": per(scoped.seconds("deq_solve"), busy_s, 100.0),
-        "backward_share": per(scoped.seconds("implicit_backward"), busy_s,
-                              100.0),
-        "block_eval_ms": per(scoped.seconds("deq_solve", "deq_block"),
-                             iterations, 1000.0),
-        "qn_update_ms": per(scoped.seconds("deq_solve", "qn_update"),
-                            iterations, 1000.0),
+        "solve_share": per(seconds("deq_solve"), busy_s, 100.0),
+        "backward_share": per(seconds("implicit_backward"), busy_s, 100.0),
+        "block_eval_ms": per(seconds("deq_solve", "deq_block"), iterations,
+                             1000.0),
+        "qn_update_ms": per(seconds("deq_solve", "qn_update"), iterations,
+                            1000.0),
     }

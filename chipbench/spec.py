@@ -56,12 +56,18 @@ class ModelSpec:
 def load(name: str) -> tuple[ModelSpec, dict]:
     """Read ``configs/<name>.json``; returns the spec and the raw file."""
     path = CONFIG_DIR / f"{name}.json"
-    raw = json.loads(path.read_text())
-    if raw.get("name") != name:
-        raise ValueError(f"{path} names itself {raw.get('name')!r}")
+    spec, raw = load_file(path)
+    if spec.name != name:
+        raise ValueError(f"{path} names itself {spec.name!r}")
+    return spec, raw
+
+
+def load_file(path: str | Path) -> tuple[ModelSpec, dict]:
+    """Read a configuration file from any path."""
+    raw = json.loads(Path(path).read_text())
     deq = raw["deq"]
     spec = ModelSpec(
-        name=name,
+        name=raw["name"],
         program_arch=raw["program_arch"],
         d=raw["hidden_size"],
         heads=raw["num_attention_heads"],

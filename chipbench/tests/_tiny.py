@@ -25,7 +25,7 @@ def small_spec(name="minicpm-2b-deq"):
                                d_ff=320, vocab=1000)
 
 
-def train_run(seed=2 ** 33 + 5, spec=None, **mix_kw):
+def train_run(seed=2 ** 33 + 5, spec=None, devices=None, **mix_kw):
     import jax
 
     mix = traffic.load("train-b8-s512")
@@ -33,7 +33,7 @@ def train_run(seed=2 ** 33 + 5, spec=None, **mix_kw):
     return runctx.Run(cell="minicpm-2b-deq.train",
                       spec=spec or small_spec(), mix=mix,
                       seed=seed, seconds=1.0, trace=False,
-                      devices=jax.devices(),
+                      devices=devices or jax.devices(),
                       limits=runctx.limits("minicpm-2b-deq.train"))
 
 

@@ -1,7 +1,8 @@
-"""Device time per named scope (``chipbench/scopes.py``): the name-stack
-reader on a hand-built trace whose answers are known, in both forms the
-``tf_op`` stat takes, and on traces recorded on a TPU v5e; and the
-existing reduction of the recorded forward trace, pinned."""
+"""Device time per named scope (``chipbench/scopes.py``,
+``Reduced.scope_seconds``): the name-stack reader on a hand-built trace
+whose answers are known, in both forms the ``tf_op`` stat takes, and on
+traces recorded on a TPU v5e; and the existing reduction of the recorded
+forward trace, pinned."""
 
 import json
 import math
@@ -99,19 +100,18 @@ def test_name_stacks_as_string_and_as_reference(hand_built):
 
 
 def test_scope_seconds_leave_the_container_out(hand_built):
-    sc = scopes.read(hand_built)
-    us = 1e-6
-    assert sc.seconds("deq_solve") == pytest.approx(6 * us)
-    assert sc.seconds("deq_solve", "deq_block") == pytest.approx(4 * us)
-    assert sc.seconds("deq_solve", "qn_update") == pytest.approx(2 * us)
-    # clipped at the window's end (13 us)
-    assert sc.seconds("implicit_backward") == pytest.approx(1 * us)
-    assert sc.seconds("deq_block") == pytest.approx(5 * us)
-    assert sc.seconds("serve_tick") == 0.0
-    assert not sc.found("serve_tick") and sc.found("qn_update")
     r = trace.reduce(hand_built)
+    us = 1e-6
+    assert r.scope_seconds("deq_solve") == pytest.approx(6 * us)
+    assert r.scope_seconds("deq_solve", "deq_block") == pytest.approx(4 * us)
+    assert r.scope_seconds("deq_solve", "qn_update") == pytest.approx(2 * us)
+    # clipped at the window's end (13 us)
+    assert r.scope_seconds("implicit_backward") == pytest.approx(1 * us)
+    assert r.scope_seconds("deq_block") == pytest.approx(5 * us)
+    assert r.scope_seconds("serve_tick") == 0.0
+    assert not r.found("serve_tick") and r.found("qn_update")
     assert r.busy_s == pytest.approx(12 * us)
-    split = scopes.train_split(sc, r.busy_s, iterations=2)
+    split = scopes.train_split(r, iterations=2)
     assert split == pytest.approx({"solve_share": 50.0,
                                    "backward_share": 100 / 12,
                                    "block_eval_ms": 2e-3,
@@ -119,12 +119,12 @@ def test_scope_seconds_leave_the_container_out(hand_built):
 
 
 def test_missing_scope_reads_none(hand_built):
-    sc = scopes.read(hand_built)
-    sc.ops = [op for op in sc.ops if "qn_update" not in op[2]]
-    split = scopes.train_split(sc, 1.0, iterations=2)
+    r = trace.reduce(hand_built)
+    r.ops = [op for op in r.ops if "qn_update" not in op.stack]
+    split = scopes.train_split(r, iterations=2)
     assert split["qn_update_ms"] is None
     assert split["block_eval_ms"] is not None
-    assert scopes.train_split(sc, 1.0, iterations=0)["block_eval_ms"] is None
+    assert scopes.train_split(r, iterations=0)["block_eval_ms"] is None
 
 
 @pytest.mark.parametrize("stack, names", [
@@ -146,10 +146,10 @@ def test_recorded_forward_has_name_stacks():
     assert any("/while/body/" in s for s in with_stack)
     assert any("jit(flash_attention_pallas)/pallas_call" in s
                for s in with_stack)
-    # every op of the reduction carries its stack
-    sc = scopes.read(FORWARD, annotations=("step", "host_gap"))
+    # the reduction's ops carry their stacks and chips
     r = trace.reduce(FORWARD, annotations=("step", "host_gap"))
-    assert [name for name, _, _ in sc.ops] == [o.name for o in r.ops]
+    assert sum(1 for o in r.ops if o.stack) > 0.9 * len(r.ops)
+    assert {o.device for o in r.ops} == {0}
 
 
 def test_recorded_forward_reduction_pinned():
@@ -180,36 +180,65 @@ def recorded_train():
     """One train step at d=256, S=128, 2 blocks, B=8, traced on a TPU v5e
     (``tools/scope_split.py --small``), and its counters."""
     counters = json.loads((DATA / "v5e_train_scopes.json").read_text())
-    return scopes.read(TRAIN), trace.reduce(TRAIN), counters
+    return trace.reduce(TRAIN), counters
 
 
 def test_recorded_train_finds_every_scope(recorded_train):
-    sc, _, _ = recorded_train
+    r, _ = recorded_train
     for scope in SCOPES:
-        assert sc.found(scope), scope
-        assert sc.seconds(scope) > 0, scope
+        assert r.found(scope), scope
+        assert r.scope_seconds(scope) > 0, scope
+
+
+def test_recorded_train_scope_seconds_pinned(recorded_train):
+    """``Reduced.scope_seconds`` gives what the recording's own split gave
+    (the ``seconds`` of its counters file, per chip)."""
+    r, counters = recorded_train
+    assert counters["seconds"]
+    for key, want in counters["seconds"].items():
+        assert r.scope_seconds(*key.split("/")) == pytest.approx(
+            want, rel=1e-12), key
 
 
 def test_recorded_train_scopes_nest(recorded_train):
-    sc, r, _ = recorded_train
-    solve = sc.seconds("deq_solve")
-    assert (sc.seconds("deq_solve", "deq_block")
-            + sc.seconds("deq_solve", "qn_update")) <= solve
-    assert solve + sc.seconds("implicit_backward") <= r.busy_s
+    r, _ = recorded_train
+    solve = r.scope_seconds("deq_solve")
+    assert (r.scope_seconds("deq_solve", "deq_block")
+            + r.scope_seconds("deq_solve", "qn_update")) <= solve
+    assert solve + r.scope_seconds("implicit_backward") <= r.busy_s
     # the forward solve's ops are not the backward's
-    assert sc.seconds("deq_solve", "implicit_backward") == 0.0
+    assert r.scope_seconds("deq_solve", "implicit_backward") == 0.0
     # the fused kernel sits in the qN update of the forward solve
-    kernel = sum(dur for name, dur, stack in sc.ops
-                 if trace.base_name(name) == "broyden_step_pallas")
+    kernel = sum(o.dur_ns for o in r.ops
+                 if trace.base_name(o.name) == "broyden_step_pallas")
     assert kernel > 0
-    assert sc.seconds("deq_solve", "qn_update") >= kernel * 1e-9
+    assert r.scope_seconds("deq_solve", "qn_update") >= kernel * 1e-9
 
 
 def test_recorded_train_split_is_finite(recorded_train):
-    sc, r, counters = recorded_train
-    split = scopes.train_split(sc, r.busy_s, sum(counters["deq_steps"]))
+    r, counters = recorded_train
+    split = scopes.train_split(r, sum(counters["deq_steps"]))
     assert set(split) == {"solve_share", "backward_share", "block_eval_ms",
                           "qn_update_ms"}
     for name, value in split.items():
         assert value is not None and math.isfinite(value) and value > 0, name
     assert split["solve_share"] + split["backward_share"] <= 100.0
+
+
+@pytest.mark.parametrize("name", ["solve_share", "backward_share",
+                                  "block_eval_ms", "qn_update_ms"])
+def test_scope_metric_readers(recorded_train, name):
+    """Each per-layer reader gives its entry of the split that
+    ``tools/scope_split.py`` prints for the same trace, and nothing for a
+    run without one."""
+    import types
+
+    from chipbench.run import reader
+
+    r, counters = recorded_train
+    run = types.SimpleNamespace(reduced_trace=r,
+                                counters={"deq_steps": counters["deq_steps"]})
+    split = scopes.train_split(r, sum(counters["deq_steps"]))
+    assert reader(f"{name}.train")(run) == split[name]
+    run.reduced_trace = None
+    assert reader(f"{name}.train")(run) is None

@@ -1,6 +1,7 @@
 """The traffic generator: deterministic for a seed, faithful to the mix's
 parameters, and the same amount of work for every seed."""
 
+import json
 import sys
 from pathlib import Path
 
@@ -57,3 +58,26 @@ def test_unknown_kind_is_refused(tmp_path, monkeypatch):
     monkeypatch.setattr(traffic, "WORKLOAD_DIR", tmp_path)
     with pytest.raises(ValueError):
         traffic.load("odd")
+
+
+@pytest.mark.parametrize("extra", [
+    {"mesh": {"data": 2}},
+    {"mesh": {"data": 2, "model": 0}},
+    {"mesh": [2, 2]},
+    {"mesh": {"data": 2, "model": 2, "pod": 1}},
+    {"mesh": {"data": 2.0, "model": 2}},
+    {"mesh": {"data": True, "model": 2}},
+])
+def test_bad_mesh_is_refused(tmp_path, extra):
+    path = tmp_path / "odd.json"
+    path.write_text(json.dumps(dict(traffic.load("train-b8-s512"), **extra)))
+    with pytest.raises(ValueError):
+        traffic.load_file(path)
+
+
+def test_mesh_of_a_mix():
+    mix = traffic.load("train-b8-s512")
+    assert traffic.mesh_shape(mix) is None and traffic.chips_needed(mix) == 1
+    mix.update(mesh={"data": 2, "model": 2})
+    assert traffic.mesh_shape(mix) == (2, 2)
+    assert traffic.chips_needed(mix) == 4

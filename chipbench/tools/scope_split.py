@@ -6,7 +6,7 @@ how the device time splits by the program's named scopes.
 
 Without ``--small``: the cell ``minicpm-2b-deq.train`` at its size, set up
 as the train job does and traced for the mix's ``trace_seconds``; the trace
-is read and removed.  With ``--small``: d=256, S=128, a group of
+is reduced as a traced run reduces it (``trace.reduce``) and removed.  With ``--small``: d=256, S=128, a group of
 2 blocks, B=8, one traced step; the trace goes to ``OUT`` without its
 ``/host:metadata`` plane (the compiled modules, which no reduction reads),
 and the window's counters beside it, under its stem with ``.json`` (the
@@ -21,9 +21,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
-import os
 import sys
-import tempfile
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -33,17 +31,17 @@ CELL = "minicpm-2b-deq.train"
 SCOPES = ("deq_solve", "deq_block", "qn_update", "implicit_backward")
 
 
-def by_kind(scoped, scope: str, n: int = 6) -> list:
+def by_kind(reduced, scope: str, n: int = 6) -> list:
     from chipbench.scopes import scope_names
     from chipbench.trace import CONTAINERS, base_name
 
     tot: dict[str, int] = {}
-    for name, dur, stack in scoped.ops:
-        kind = base_name(name)
-        if kind not in CONTAINERS and scope in scope_names(stack):
-            tot[kind] = tot.get(kind, 0) + dur
+    for op in reduced.ops:
+        kind = base_name(op.name)
+        if kind not in CONTAINERS and scope in scope_names(op.stack):
+            tot[kind] = tot.get(kind, 0) + op.dur_ns
     top = sorted(tot.items(), key=lambda kv: -kv[1])[:n]
-    return [[k, ns * 1e-9 / scoped.n_devices] for k, ns in top]
+    return [[k, ns * 1e-9 / reduced.n_devices] for k, ns in top]
 
 
 def _varint_bytes(n: int) -> bytes:
@@ -94,8 +92,7 @@ def main(argv=None) -> int:
         mix.update(seq=128)
         seconds, keep = 0.0, Path(args.small)
     else:
-        seconds = mix["trace_seconds"]
-        keep = Path(tempfile.mkdtemp(dir=os.environ.get("TMPDIR"))) / "t.pb"
+        seconds, keep = mix["trace_seconds"], None
     run = runctx.Run(cell=CELL, spec=spec, mix=mix, seed=args.seed,
                      seconds=seconds, trace=True, devices=jax.devices()[:1],
                      limits=runctx.limits(CELL))
@@ -105,36 +102,29 @@ def main(argv=None) -> int:
     capture.start()
     state, n, dt, mets = train.window(run, step, state, feed, first,
                                       seconds, traced=False)
-    reduced = capture.stop(keep=str(keep))
+    reduced = capture.stop(keep=keep and str(keep))
     iters = sum(float(m["deq_steps"]) for m in mets)
-    scoped = scopes.read(keep)
-    solve = scoped.seconds("deq_solve")
-    backward = scoped.seconds("implicit_backward")
+    seconds = {key: reduced.scope_seconds(*key.split("/"))
+               for key in ("deq_solve", "implicit_backward",
+                           "deq_solve/deq_block", "deq_solve/qn_update",
+                           "implicit_backward/deq_block")}
     out = {"device": jax.devices()[0].device_kind, "steps": n,
            "host_window_s": dt, "window_s": reduced.window_s,
            "busy_s": reduced.busy_s, "iterations": iters,
-           "found": {s: scoped.found(s) for s in SCOPES},
-           "seconds": {
-               "deq_solve": solve, "implicit_backward": backward,
-               "rest": reduced.busy_s - solve - backward,
-               "deq_solve/deq_block": scoped.seconds("deq_solve",
-                                                     "deq_block"),
-               "deq_solve/qn_update": scoped.seconds("deq_solve",
-                                                     "qn_update"),
-               "implicit_backward/deq_block": scoped.seconds(
-                   "implicit_backward", "deq_block")},
-           "split": scopes.train_split(scoped, reduced.busy_s, iters),
-           "kinds": {s: by_kind(scoped, s) for s in SCOPES},
+           "found": {s: reduced.found(s) for s in SCOPES},
+           "seconds": dict(seconds, rest=reduced.busy_s
+                           - seconds["deq_solve"]
+                           - seconds["implicit_backward"]),
+           "split": scopes.train_split(reduced, iters),
+           "kinds": {s: by_kind(reduced, s) for s in SCOPES},
            "top_ops": reduced.top_ops(10)}
-    if not args.small:
-        keep.unlink()
-        keep.parent.rmdir()
-    else:
+    if keep:
         keep.write_bytes(drop_planes(keep.read_bytes(), ("/host:metadata",)))
         keep.with_name(keep.name.split(".")[0] + ".json").write_text(
             json.dumps(
                 {"steps": n,
                  "deq_steps": [float(m["deq_steps"]) for m in mets],
+                 "seconds": seconds,
                  "seed": args.seed, "device": out["device"],
                  "spec": dataclasses.asdict(spec),
                  "batch": mix["batch"], "seq": mix["seq"]}, indent=1) + "\n")

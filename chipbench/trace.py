@@ -1,10 +1,12 @@
 """Capture of a profiler trace around a window, and its reduction to what
-the per-layer metrics read: device busy time, per-operation device time,
-and idle gaps labelled by the harness annotation open on the host.
+the per-layer metrics read: device busy time, per-operation device time
+by op name or by the program's named scopes, and idle gaps labelled by the
+harness annotation open on the host.
 
 Device planes are the ``/device:TPU:<n>`` planes of the trace; their
-operations are the events of the ``XLA Ops`` line.  The window in the
-trace's clock runs from the first to the last harness annotation.
+operations are the events of the ``XLA Ops`` line, each with its chip and
+its name stack (``scopes.op_stacks``).  The window in the trace's clock
+runs from the first to the last harness annotation.
 """
 
 from __future__ import annotations
@@ -44,6 +46,8 @@ class Op:
     start_ns: int
     dur_ns: int
     detail: str      # the op's long name / source op, where the trace has it
+    device: int = 0  # index of its chip among the trace's device planes
+    stack: str = ""  # its name stack (``tf_op``), "" where it has none
 
 
 @dataclasses.dataclass
@@ -54,12 +58,37 @@ class Reduced:
     ops: list[Op]                    # device ops inside the window, all chips
     gaps: list[tuple[str, float]]    # 10 longest: (host annotation, s)
 
-    def op_seconds(self, pattern: str) -> tuple[float, int]:
-        """Summed device seconds and count of the ops whose name or detail
-        matches ``pattern`` (a regular expression)."""
+    def op_seconds(self, pattern: str,
+                   per_chip: bool = False) -> tuple[float, float]:
+        """Device seconds and count of the ops whose name or detail matches
+        ``pattern`` (a regular expression): summed over the chips, or with
+        ``per_chip`` their mean per chip."""
         rx = re.compile(pattern)
         hits = [o for o in self.ops if rx.search(o.name) or rx.search(o.detail)]
-        return sum(o.dur_ns for o in hits) * 1e-9, len(hits)
+        seconds, count = sum(o.dur_ns for o in hits) * 1e-9, len(hits)
+        if per_chip:
+            return seconds / self.n_devices, count / self.n_devices
+        return seconds, count
+
+    def scope_seconds(self, *scopes: str) -> float:
+        """Device seconds, mean per chip, of the ops whose name stack holds
+        every one of ``scopes`` as a path segment (bare, or wrapped by a
+        transform as in ``jvp(deq_solve)``).  Control-flow containers are
+        left out: their bodies' ops are counted on their own.  0 where no op
+        carries the scopes."""
+        from chipbench.scopes import scope_names
+
+        want = frozenset(scopes)
+        ns = sum(o.dur_ns for o in self.ops
+                 if o.stack and want <= scope_names(o.stack)
+                 and base_name(o.name) not in CONTAINERS)
+        return ns * 1e-9 / self.n_devices
+
+    def found(self, scope: str) -> bool:
+        """Whether any op's name stack holds ``scope``."""
+        from chipbench.scopes import scope_names
+
+        return any(scope in scope_names(o.stack) for o in self.ops if o.stack)
 
     def top_ops(self, n: int = 10) -> list[list]:
         """The ``n`` op kinds (HLO name without its number) that took most
@@ -101,14 +130,22 @@ def reduce(path: str | Path, annotations=ANNOTATIONS) -> Reduced:
     """Reduce one ``.xplane.pb`` file."""
     from jax.profiler import ProfileData
 
+    from chipbench.scopes import op_stacks
+
     pd = ProfileData.from_file(str(path))
+    stacks = op_stacks(path)
     host_spans: list[tuple[int, int, str]] = []
     device_lines = []
     for plane in pd.planes:
         if DEVICE_PLANE.match(plane.name):
             lines = {line.name: line for line in plane.lines}
             if OPS_LINE in lines:
-                device_lines.append(list(lines[OPS_LINE].events))
+                evs = list(lines[OPS_LINE].events)
+                named = stacks.get(plane.name, [])
+                if [n for n, _ in named] != [e.name for e in evs]:
+                    named = [("", "")] * len(evs)
+                device_lines.append([(e, st) for e, (_, st) in
+                                     zip(evs, named)])
         elif plane.name.startswith("/host"):
             for line in plane.lines:
                 for ev in line.events:
@@ -123,21 +160,22 @@ def reduce(path: str | Path, annotations=ANNOTATIONS) -> Reduced:
         w0 = min(s for s, _, _ in host_spans)
         w1 = max(e for _, e, _ in host_spans)
     else:
-        starts = [int(e.start_ns) for evs in device_lines for e in evs]
-        ends = [int(e.end_ns) for evs in device_lines for e in evs]
+        starts = [int(e.start_ns) for evs in device_lines for e, _ in evs]
+        ends = [int(e.end_ns) for evs in device_lines for e, _ in evs]
         w0, w1 = min(starts), max(ends)
     ops: list[Op] = []
     busy_ns = 0
     raw_gaps: list[tuple[int, int]] = []
     host_spans.sort()
-    for evs in device_lines:
+    for chip, evs in enumerate(device_lines):
         ivs = []
-        for e in evs:
+        for e, stack in evs:
             s, t = max(int(e.start_ns), w0), min(int(e.end_ns), w1)
             if t <= s:
                 continue
             ivs.append((s, t))
-            ops.append(Op(short_name(e.name), s, t - s, _detail(e)))
+            ops.append(Op(short_name(e.name), s, t - s, _detail(e), chip,
+                          stack))
         merged = _union(ivs)
         busy_ns += sum(t - s for s, t in merged)
         edges = [w0] + [x for iv in merged for x in iv] + [w1]

@@ -7,6 +7,9 @@ group are further scaled by the configuration's ``deq_weight_scale`` so that
 the fixed-point map is contractive, as a trained DEQ is.  Rows of the
 embedding (and columns of an untied head) past the published vocabulary are
 zero, so no padded id is ever the best token.
+
+The values depend on the seed alone: laid out over several devices, each
+device makes its own part, and the weights are the same bits as on one.
 """
 
 from __future__ import annotations
@@ -33,7 +36,6 @@ def _matrix(key, shape, fan_in, scale, dtype):
     return (w * (scale / fan_in ** 0.5)).astype(dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 2))
 def _make(spec: ModelSpec, key: jax.Array, dtype) -> dict:
     d, nb, ff = spec.d, spec.blocks, spec.d_ff
     ad, kvd = spec.heads * spec.head_dim, spec.kv_heads * spec.head_dim
@@ -65,7 +67,22 @@ def _make(spec: ModelSpec, key: jax.Array, dtype) -> dict:
             "deq_blocks": blocks}
 
 
-def make_params(spec: ModelSpec, seed: int, dtype=jnp.bfloat16) -> dict:
-    """The configuration's weights for ``seed``, made on the default
-    device."""
-    return _make(spec, key_from_seed(seed), jnp.dtype(dtype))
+@functools.lru_cache(maxsize=None)
+def _maker(spec: ModelSpec, dtype, layout=None):
+    """One jitted maker per configuration, type and layout (a tree
+    definition and its shardings, flat, so that it can key the cache)."""
+    make = functools.partial(_make, spec, dtype=dtype)
+    if layout is None:
+        return jax.jit(make)
+    return jax.jit(make, out_shardings=jax.tree_util.tree_unflatten(*layout))
+
+
+def make_params(spec: ModelSpec, seed: int, dtype=jnp.bfloat16,
+                shardings=None) -> dict:
+    """The configuration's weights for ``seed``: on the default device, or
+    laid out as ``shardings`` says (a tree of shardings, one per leaf)."""
+    layout = None
+    if shardings is not None:
+        leaves, treedef = jax.tree_util.tree_flatten(shardings)
+        layout = (treedef, tuple(leaves))
+    return _maker(spec, jnp.dtype(dtype), layout)(key_from_seed(seed))
